@@ -55,6 +55,13 @@ def run():
     # Shapes match pipeline_bench's chunk: Q=64 queries x C=2048 gathered
     # candidates (run=64 ascending windows) against n=131072 points.
     from repro.core import pipeline
+    from repro.kernels import blocking
+
+    if not blocking.resolve_interpret(None):
+        # compiled, there is no fused f32 tail to time: Mosaic refuses its
+        # body (query_fused.XLA_STAGES) and the pallas backend runs the chain
+        yield ("kernel/query_tail_fused_over_staged", 0.0, "fused=interpret-only")
+        return
 
     n, d, q_n, c_total, run_len, cc, k = 131072, 64, 64, 2048, 64, 256, 10
     data = jax.random.uniform(jax.random.PRNGKey(1), (n, d))

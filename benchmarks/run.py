@@ -11,6 +11,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         elastic_bench,
         fig3_tradeoff,

@@ -58,7 +58,7 @@ def _cfg(**kw):
     return common.slsh_cfg(**kw)
 
 
-def _stream_dataset(n: int, nq: int):
+def stream_dataset(n: int, nq: int):
     """Assemble (points, labels, qx, qy) from the chunked window stream.
 
     The stream is consumed chunk-by-chunk into preallocated arrays — the
@@ -83,8 +83,12 @@ def _stream_dataset(n: int, nq: int):
 
 
 def _probe_rss(mode: str, n: int) -> dict:
-    """One subprocess single-shard build; returns its RSS accounting."""
-    env = dict(os.environ)
+    """One subprocess single-shard build; returns its RSS accounting.
+
+    The child measures host RSS, so it runs on the CPU backend: this
+    process already holds the accelerator, and a chip serves one process.
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (
         os.path.join(os.path.dirname(__file__), "..", "src")
         + os.pathsep
@@ -112,7 +116,7 @@ def _probe_child(mode: str, n: int) -> None:
         return 0
 
     cfg = _cfg(build_mode=mode)
-    pts, _, _, _ = _stream_dataset(n, 0)
+    pts, _, _, _ = stream_dataset(n, 0)
     data = jnp.asarray(pts)
     del pts
     jax.block_until_ready(data)
@@ -128,7 +132,8 @@ def _probe_child(mode: str, n: int) -> None:
     wall = time.perf_counter() - t0
     peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
     print(json.dumps({
-        "mode": mode, "n": n, "pre_kb": pre, "peak_kb": peak,
+        "mode": mode, "platform": jax.default_backend(), "n": n,
+        "pre_kb": pre, "peak_kb": peak,
         "build_delta_kb": max(peak - pre, 0), "wall_s": wall,
     }))
 
@@ -179,7 +184,7 @@ def run():
 
     # ---- dataset (streamed assembly)
     t0 = time.perf_counter()
-    pts, labs, qx, qy = _stream_dataset(n, nq)
+    pts, labs, qx, qy = stream_dataset(n, nq)
     gen_s = time.perf_counter() - t0
     pts, labs, n_real = dslsh.pad_to_multiple(pts, labs, NU * P)
     n_pad = pts.shape[0]

@@ -18,7 +18,9 @@ import numpy as np
 from repro import dslsh
 from repro.core import predict
 from repro.data import abp, windows
-from repro.runtime import ft
+from repro.runtime import compile_cache, ft
+
+compile_cache.enable()
 
 # dataset
 cfg_abp = abp.ABPConfig(n_beats=60_000, episode_rate=1.0 / 2500.0)

@@ -10,6 +10,9 @@ import numpy as np
 from repro import dslsh
 from repro.core import predict
 from repro.data import abp, windows
+from repro.runtime import compile_cache
+
+compile_cache.enable()
 
 # 1. Synthesize ABP (MAP) waveforms and build the rolling-window dataset.
 cfg_abp = abp.ABPConfig(n_beats=60_000, episode_rate=1.0 / 2500.0)

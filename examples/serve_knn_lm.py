@@ -17,8 +17,11 @@ from repro.data.lm_data import TokenStream
 from repro.models import api
 from repro.models.api import ModelConfig
 from repro.optim import adamw
+from repro.runtime import compile_cache
 from repro.serve import engine
 from repro.train import loop as tl
+
+compile_cache.enable()
 
 cfg = ModelConfig(
     name="serve-demo", family="dense",

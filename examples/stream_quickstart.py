@@ -15,6 +15,9 @@ import numpy as np
 
 from repro import dslsh, stream
 from repro.data import abp, windows
+from repro.runtime import compile_cache
+
+compile_cache.enable()
 
 # --- dataset: 8 synthetic ABP records; 7 historical + 1 live (paper §4)
 cfg_abp = abp.ABPConfig(n_beats=60_000, episode_rate=1.0 / 2500.0)

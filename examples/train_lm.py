@@ -16,10 +16,12 @@ from repro.data.lm_data import TokenStream
 from repro.models import api
 from repro.models.api import ModelConfig
 from repro.optim import adamw
+from repro.runtime import compile_cache
 from repro.train import loop as tl
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=4)

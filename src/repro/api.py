@@ -43,6 +43,7 @@ Configuration is composed, not flat: :func:`make_config` combines a
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -640,11 +641,15 @@ class Index:
         return self._compiled["payload"]
 
     def _single_fn(self):
+        """``q -> result`` over this handle's state. The index, data and
+        payload enter the jitted program as arguments: closed over, they
+        would be baked into every compiled executable as constants."""
         if "q" not in self._compiled:
-            index, data = self._state["index"], self._state["data"]
-            cfg, payload = self.cfg, self._payload()
+            state = (self._state["index"], self._state["data"], self._payload())
+            cfg = self.cfg
 
-            def run(q):
+            def run(st, q):
+                index, data, payload = st
                 obs_mod.count_retrace("single_query")
                 res = pipeline.query_batch(index, data, q, cfg, payload=payload)
                 return DistributedQueryResult(
@@ -657,16 +662,24 @@ class Index:
                     else res.rerank_misses[None, None],
                 )
 
-            self._compiled["q"] = jax.jit(run)
+            self._compiled["q"] = functools.partial(jax.jit(run), state)
         return self._compiled["q"]
 
     def _grid_fn(self, max_cells: int | None):
+        """``(q, drop_mask, drop_cells) -> result``; the index, data and
+        the plan's device-side occupancy map enter as arguments, as in
+        :meth:`_single_fn` (the plan's placement fields are host-side
+        numpy that shapes the trace)."""
         key = ("q", max_cells)
         if key not in self._compiled:
-            index, data = self._state["index"], self._state["data"]
-            cfg, g, plan = self.cfg, self.grid, self.plan
+            host_plan = self.plan
+            occupancy = None if host_plan is None else host_plan.occupancy
+            state = (self._state["index"], self._state["data"], occupancy)
+            cfg, g = self.cfg, self.grid
 
-            def run(q, dm, dc):
+            def run(st, q, dm, dc):
+                index, data, occ = st
+                plan = None if occ is None else host_plan._replace(occupancy=occ)
                 # count_retrace runs only while tracing: the §15 serving
                 # pin reads this stage to prove steady state retraces
                 # nothing after the ladder warmup
@@ -676,7 +689,7 @@ class Index:
                     drop_mask=dm, drop_cells=dc,
                 )
 
-            self._compiled[key] = jax.jit(run)
+            self._compiled[key] = functools.partial(jax.jit(run), state)
         return self._compiled[key]
 
     # --------------------------------------------------------- streaming

@@ -99,7 +99,11 @@ def signature_bits(params: HashParams, x: jax.Array) -> jax.Array:
     if isinstance(params, BitSampleParams):
         gathered = x[:, params.dims]  # (n, L, m)
         return gathered > params.thrs[None]
-    proj = jnp.einsum("nd,ldm->nlm", x, params.proj)
+    # full f32 precision: TPU matmuls default to bf16 passes, which would
+    # flip bits near zero and make keys depend on the platform
+    proj = jnp.einsum(
+        "nd,ldm->nlm", x, params.proj, precision=jax.lax.Precision.HIGHEST
+    )
     return proj >= 0.0
 
 

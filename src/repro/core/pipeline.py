@@ -578,12 +578,26 @@ def _pallas_query_tail_payload(
 
 
 def _pallas_ops(cfg: "SLSHConfig | None") -> BackendOps:
+    """The pallas backend: Mosaic kernels, compiled on TPU and interpreted
+    elsewhere (``blocking.resolve_interpret``; ``cfg.interpret`` forces
+    either). Compiled, Mosaic refuses the fused f32 tail's body
+    (``kernels.query_fused.query_fused.XLA_STAGES``), so the backend has no
+    ``query_tail`` there: the f32 tail runs the staged stages 3-5 as XLA
+    ops, stage 5 in the ``l1_topk`` kernel. The compressed-payload tail
+    keeps its dedup, compaction, gathers and selections as XLA ops around
+    the ``l1_topk`` kernel's distance mode."""
+    from repro.kernels import blocking
+
     interp = None if cfg is None else cfg.interpret
+    compiled = not blocking.resolve_interpret(interp)
     return BackendOps(
         functools.partial(_pallas_signature_words, interpret=interp),
         functools.partial(_pallas_l1_topk, interpret=interp),
         probe_words=functools.partial(_pallas_probe_words, interpret=interp),
-        query_tail=functools.partial(_pallas_query_tail, interpret=interp),
+        query_tail=(
+            None if compiled
+            else functools.partial(_pallas_query_tail, interpret=interp)
+        ),
         query_tail_payload=functools.partial(
             _pallas_query_tail_payload, interpret=interp
         ),
@@ -820,41 +834,14 @@ def _build_tables_chunked_eager(
     return tables.TableSet(out_k, out_i)
 
 
-def _build_tables_chunked_traced(
-    outer_params: hashing.BitSampleParams,
-    data: jax.Array,
-    cfg: SLSHConfig,
-    backend: BackendOps,
-) -> tables.TableSet:
-    """Chunked sorted-run construction, traceable form (all tables at once).
-
-    Used when the caller is already inside a jit (``distributed
-    simulate_build`` maps cells under ``lax.map``): the chunk loop unrolls
-    into the trace, XLA owns the memory schedule, and the result is
-    bit-identical to the eager schedule and the monolithic oracle.
-    """
-    n = data.shape[0]
-    chunk = min(cfg.build_chunk, n)
-    stack: list[merge.Run] = []
-    for lo, hi in _chunk_bounds(n, chunk):
-        kg = hash_keys(outer_params, data[lo:hi], backend).T  # (L, c)
-        ig = jnp.broadcast_to(jnp.arange(lo, hi, dtype=jnp.int32), kg.shape)
-        item = tuple(
-            jax.vmap(lambda kk, ii: jax.lax.sort((kk, ii), num_keys=1))(kg, ig)
-        )
-        merge.ladder_push(stack, item)
-    return tables.TableSet(*merge.ladder_collapse(stack))
-
-
 def _pick_build_mode(cfg: SLSHConfig, n: int) -> str:
     """Resolve ``cfg.build_mode`` for an ``n``-point build: ``"auto"``
     goes chunked only past one ``build_chunk`` of points (a single-chunk
-    ladder is the monolithic sort with extra steps), and ``n == 0`` always
-    takes the trivial full sort (no runs to merge)."""
+    ladder is the monolithic sort with extra steps)."""
     mode = cfg.build_mode
     if mode == "auto":
         mode = "chunked" if n > cfg.build_chunk else "monolithic"
-    return "monolithic" if n == 0 else mode
+    return mode
 
 
 def build_from_params(
@@ -875,9 +862,11 @@ def build_from_params(
     on every output (tests/test_property_build.py). ``"auto"`` goes
     chunked once ``n > build_chunk``. The chunked path also streams the
     heavy-bucket scan per table (``tables.find_heavy_streamed``), whose
-    all-tables transients would otherwise dominate peak build memory.
+    all-tables transients would otherwise dominate peak build memory; under
+    a trace, that streamed scan is all it keeps of the chunked schedule.
     """
     n = data.shape[0]
+    _require(n >= 1, "cannot build an SLSH index over zero points")
     backend = get_backend(cfg.backend, cfg)
     l_out = outer_params.salts.shape[0]
     traced = _contains_tracer(data, outer_params, inner_params)
@@ -885,23 +874,26 @@ def build_from_params(
     ob = obs_mod.get_active()
     if ob is not None and (traced or not ob.tracing):
         ob = None  # sync-point policy: build spans only under eager tracing
-    if mode == "chunked":
-        if traced:
-            outer = _build_tables_chunked_traced(outer_params, data, cfg, backend)
-        else:
-            outer = _build_tables_chunked_eager(outer_params, data, cfg, ob)
-        find_heavy = tables.find_heavy_streamed
+    # Under a trace (grid and mesh cell programs) even the chunked mode
+    # sorts each table whole after chunk-mapped hashing: the sorted-run
+    # ladder would unroll into the program, one hash kernel and one merge
+    # per chunk — 168 of each per cell of a 2x2 mesh at 1.37M points, 186.5 s
+    # of compile for a v5e — while XLA owns that program's memory schedule
+    # either way. Both forms are bit-exact with each other.
+    if mode == "chunked" and not traced:
+        outer = _build_tables_chunked_eager(outer_params, data, cfg, ob)
+    elif ob is None:
+        keys = hash_keys_chunked(outer_params, data, cfg.build_chunk, backend)
+        outer = tables.build_tables(keys)
     else:
-        if ob is None:
-            keys = hash_keys_chunked(outer_params, data, cfg.build_chunk, backend)
-            outer = tables.build_tables(keys)
-        else:
-            keys = _traced_stage(
-                ob, "build.hash", hash_keys_chunked,
-                outer_params, data, cfg.build_chunk, backend,
-            )
-            outer = _traced_stage(ob, "build.sort_runs", tables.build_tables, keys)
-        find_heavy = tables.find_heavy
+        keys = _traced_stage(
+            ob, "build.hash", hash_keys_chunked,
+            outer_params, data, cfg.build_chunk, backend,
+        )
+        outer = _traced_stage(ob, "build.sort_runs", tables.build_tables, keys)
+    find_heavy = (
+        tables.find_heavy_streamed if mode == "chunked" else tables.find_heavy
+    )
     alpha_n = jnp.maximum(jnp.int32(cfg.alpha * n), 1)
 
     def heavy_inner():
@@ -1354,6 +1346,12 @@ def _use_payload(cfg: SLSHConfig, backend: BackendOps) -> bool:
     return cfg.payload != "f32" and backend.query_tail_payload is not None
 
 
+def _fused_tail(cfg: SLSHConfig, backend: BackendOps) -> bool:
+    """Whether stages 3-5 run as one backend tail call (the compressed-
+    payload tail, or the backend's f32 ``query_tail``) rather than staged."""
+    return _use_payload(cfg, backend) or backend.query_tail is not None
+
+
 def query_chunk(
     index: SLSHIndex,
     data: jax.Array,
@@ -1368,15 +1366,16 @@ def query_chunk(
     streaming path, DESIGN.md §9); the merged candidates flow through the
     same dedup, compaction, and L1 top-k work, so ``cfg.backend`` dispatch
     covers streaming queries too. Backends providing ``query_tail``
-    (pallas) run stages 3-5 as one fused megakernel launch
+    (pallas, interpreted) run stages 3-5 as one fused megakernel launch
     (``kernels/query_fused``, DESIGN.md §4); the staged form below is the
-    reference path and the bit-exactness oracle. When ``cfg.payload`` is
+    reference path, the bit-exactness oracle, and the compiled pallas f32
+    tail. When ``cfg.payload`` is
     compressed, the tail streams quantized rows from ``payload`` (built
     here from ``data`` when the caller holds none — handles precompute it
     once) and reranks exactly in f32 (DESIGN.md §13).
     """
     backend = get_backend(cfg.backend, cfg)
-    if backend.query_tail is not None:
+    if _fused_tail(cfg, backend):
         cand, bucket_total = _head_chunk(index, queries, cfg, backend, delta)
         cc = _compact_width(cfg, cand.shape[1], data.shape[0])
         if _use_payload(cfg, backend):
@@ -1636,7 +1635,7 @@ def query_batch(
             cfg.query_chunk,
         )
     backend = get_backend(cfg.backend, cfg)
-    if backend.query_tail is not None:
+    if _fused_tail(cfg, backend):
         return _query_batch_fused_eager(
             index, data, queries, cfg, delta, backend, payload
         )

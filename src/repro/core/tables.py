@@ -57,6 +57,8 @@ def _heavy_one_table(
     # DESIGN.md §9) — the pad segment must never be classified heavy.
     seg_key = sorted_keys[jnp.clip(starts, 0, n - 1)]
     heavy_sizes = jnp.where((sizes > alpha_n) & (seg_key != PAD_KEY), sizes, 0)
+    if n < h_max:  # fewer segments than registry slots: pad with empties
+        heavy_sizes = jnp.pad(heavy_sizes, (0, h_max - n))
     top_sizes, top_segs = jax.lax.top_k(heavy_sizes, h_max)
     valid = top_sizes > 0
     top_start = jnp.where(valid, starts[top_segs], 0)

@@ -58,29 +58,6 @@ def clamp_pow2(n: int, blk: int, lo: int = SUBLANE) -> int:
     return min(blk, max(lo, 1 << max(0, n - 1).bit_length()))
 
 
-def ring_chunk(
-    width: int,
-    d_pad: int,
-    budget_bytes: int = 1 << 20,
-    slots: int = 2,
-    itemsize: int = 4,
-) -> int:
-    """Rows per ring-buffer slot for a double-buffered HBM->VMEM gather.
-
-    A kernel streaming ``width`` gathered rows of ``d_pad`` elements
-    through ``slots`` resident tiles gets the largest sublane-multiple
-    chunk whose tiles fit ``budget_bytes`` of VMEM, clamped to ``width``
-    and floored at one sublane. Shared by the fused query-tail ring
-    (``query_fused/ops.py``) and any future gather-heavy kernel, so every
-    wrapper sizes scratch from the same budget instead of hardcoding tile
-    shapes (DESIGN.md §4).
-    """
-    per_row = max(1, d_pad * itemsize * slots)
-    rows = budget_bytes // per_row
-    rows = max(SUBLANE, (rows // SUBLANE) * SUBLANE)
-    return min(rows, max(SUBLANE, round_up(width, SUBLANE)))
-
-
 def resolve_interpret(override: bool | None = None) -> bool:
     """Interpret-mode policy: auto-off on real TPU, on everywhere else."""
     if override is not None:

@@ -2,8 +2,9 @@
 
 ``bits = (x @ proj + bias) > 0`` packed into uint32 words, so m-bit
 signatures never hit HBM as full float rows. The projection runs on the MXU
-((T_BLK, D_PAD) @ (D_PAD, M_TOTAL)); sign extraction and 32-way packing are
-VPU ops on the resident tile. Serves both LSH families (DESIGN.md §4):
+((T_BLK, D_PAD) @ (D_PAD, M_TOTAL)); sign extraction is a VPU op and the
+32-way packing two small selector contractions (``_pack_words``), both on
+the resident tile. Serves both LSH families (DESIGN.md §4):
 sign random projection (cosine) directly, and l1 bit-sampling via a one-hot
 selector matrix with bias = -thresholds.
 
@@ -27,19 +28,50 @@ from jax.experimental import pallas as pl
 
 
 def _pack_words(bits):
-    """Pack a (T_BLK, M_TOTAL) bit matrix into (T_BLK, M_TOTAL//32) words."""
-    t_blk, m_total = bits.shape
+    """Pack a (T_BLK, M_TOTAL) bit matrix into (T_BLK, M_TOTAL//32) words.
+
+    Two small MXU contractions against power-of-two selector matrices give
+    each word's low and high 16-bit halves; both operands are exact in
+    bf16 and every half-word sum stays below 2^16, so the result is exact
+    at any matmul precision. Mosaic lowers neither the lane-splitting
+    reshape a per-word reduction needs nor any reduction over unsigned
+    integers, so the halves are joined in int32 and bitcast.
+    """
+    m_total = bits.shape[1]
     w = m_total // 32
-    b32 = bits.reshape(t_blk, w, 32).astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (t_blk, w, 32), 2)
-    return jnp.sum(b32 << shifts, axis=-1, dtype=jnp.uint32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (m_total, w), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (m_total, w), 1)
+    bit = row % 32
+    own = row // 32 == col
+    b = bits.astype(jnp.float32)
+
+    def half(lo_bit):
+        sel = own & (bit >= lo_bit) & (bit < lo_bit + 16)
+        scale = jnp.left_shift(jnp.int32(1), bit - lo_bit).astype(jnp.float32)
+        part = jnp.dot(
+            b, jnp.where(sel, scale, 0.0), preferred_element_type=jnp.float32
+        )
+        return part.astype(jnp.int32)
+
+    words = half(0) | jnp.left_shift(half(16), 16)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
+def _project(x, p):
+    """``x @ p`` at full f32 precision: a one-hot selector must reproduce
+    ``x[dim]`` exactly, and sign-projection bits must match the reference
+    ``hashing.signature_bits`` (also full precision) on every platform."""
+    return jnp.dot(
+        x, p, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _hash_pack_kernel(x_ref, p_ref, b_ref, o_ref, *, m: int, m_stride: int):
     x = x_ref[...]  # (T_BLK, D_PAD)
     p = p_ref[...]  # (D_PAD, M_TOTAL)
     bias = b_ref[...]  # (1, M_TOTAL)
-    s = jnp.dot(x, p, preferred_element_type=jnp.float32) + bias  # MXU
+    s = _project(x, p) + bias  # MXU
     t_blk, m_total = s.shape
     col = jax.lax.broadcasted_iota(jnp.int32, (t_blk, m_total), 1)
     bits = (s > 0.0) & (col % m_stride < m)  # zero out padded bit positions
@@ -61,7 +93,7 @@ def _hash_pack_margins_kernel(
     x = x_ref[...]
     p = p_ref[...]
     bias = b_ref[...]
-    s = jnp.dot(x, p, preferred_element_type=jnp.float32) + bias  # MXU
+    s = _project(x, p) + bias  # MXU
     t_blk, m_total = s.shape
     col = jax.lax.broadcasted_iota(jnp.int32, (t_blk, m_total), 1)
     bits = (s > 0.0) & (col % m_stride < m)

@@ -3,17 +3,19 @@
 :func:`query_tail` is the ``BackendOps.query_tail`` implementation the
 pallas pipeline backend registers (``core/pipeline.py``, DESIGN.md §6): it
 replaces staged pipeline stages 3-5 (dedup -> compact -> gather + L1 +
-top-k) with one launch of ``query_fused.query_tail_pallas``, bit-exact
-with the staged reference path (``ref.query_tail_ref`` is the oracle).
+top-k) with ``query_fused.query_tail_pallas``, one interpreted launch,
+bit-exact with the staged reference path (``ref.query_tail_ref`` is the
+oracle). The fused f32 tail has no compiled formulation (Mosaic refuses
+its body, ``query_fused.XLA_STAGES``): compiled on a TPU, the pallas
+backend runs the staged stages with the ``l1_topk`` kernel instead.
 
 The wrapper owns the launch-shape policy so the kernel bodies stay pure:
 
 * pad the candidate width to a multiple of ``run`` and then to a
   power-of-two run count (the merge network's only shape requirement),
   with ``-1`` columns that dedup discards;
-* resolve the interpret policy (``blocking.resolve_interpret``) and size
-  the compiled path's gather ring buffer from the shared VMEM budget
-  (``blocking.ring_chunk``).
+* resolve the interpret policy (``blocking.resolve_interpret``); a
+  compiled f32 tail is refused, never interpreted in its place.
 """
 from __future__ import annotations
 
@@ -68,17 +70,18 @@ def query_tail(
     # compile-cache regression tests pin: runtime query knobs must never
     # re-trace the fused kernel (DESIGN.md §4/§12).
     count_retrace("query_tail")
-    interp = blocking.resolve_interpret(interpret)
+    if not blocking.resolve_interpret(interpret):
+        raise ValueError(
+            "the fused f32 query tail has no compiled formulation"
+            " (query_fused.XLA_STAGES); compiled, the pallas backend runs the"
+            " staged tail with the l1_topk kernel"
+        )
     c = cand.shape[1]
     c_pad = _run_padded_width(c, run)
     if c_pad != c:
         cand = blocking.pad_axis(cand, 1, c_pad, value=-1)
-    kwargs = {}
-    if not interp:
-        kwargs["c_blk"] = blocking.ring_chunk(c_comp, data.shape[1])
     return query_tail_pallas(
-        data, queries.astype(jnp.float32), cand,
-        run=run, c_comp=c_comp, k=k, interpret=interp, **kwargs,
+        data, queries.astype(jnp.float32), cand, run=run, c_comp=c_comp, k=k
     )
 
 
@@ -115,13 +118,7 @@ def query_tail_payload(
     c_pad = _run_padded_width(c, run)
     if c_pad != c:
         cand = blocking.pad_axis(cand, 1, c_pad, value=-1)
-    kwargs = {}
-    if not interp:
-        kwargs["c_blk"] = blocking.ring_chunk(
-            c_comp, qdata.shape[1], itemsize=qdata.dtype.itemsize
-        )
     return query_tail_payload_pallas(
         data, qdata, meta, queries.astype(jnp.float32), cand,
-        run=run, c_comp=c_comp, c_rerank=c_rerank, k=k,
-        interpret=interp, **kwargs,
+        run=run, c_comp=c_comp, c_rerank=c_rerank, k=k, interpret=interp,
     )

@@ -1,33 +1,28 @@
 """Pallas megakernel: the query-pipeline tail fused into one launch.
 
-One ``pallas_call`` consumes a query chunk's raw candidate tensor and
-produces the finished k-NN answer: merge the gather stage's sorted runs
-into one ascending row (a bitonic concat-merge network — no general sort),
-mask duplicate / padded slots, prefix-sum the survivor mask and compact the
+The tail consumes a query chunk's raw candidate tensor and produces the
+finished k-NN answer: merge the gather stage's sorted runs into one
+ascending row (a bitonic concat-merge network — no general sort), mask
+duplicate / padded slots, prefix-sum the survivor mask and compact the
 first ``c_comp`` unique indices, gather their data rows, and reduce L1
-distances to the top-k — so candidate vectors touch HBM exactly once and
-the ``(Q, c_comp, d)`` gathered block never materializes as an HBM
-intermediate between stages (DESIGN.md §4).
+distances to the top-k (DESIGN.md §4).
 
-Two formulations share the algorithm (DESIGN.md §4/§6):
+The fused launch is the interpret formulation (the off-TPU production +
+CI path): one ``pallas_call`` with ``grid=(1,)`` and the whole chunk
+resident; ``data`` is handed over in ``pl.ANY`` memory space and
+candidate rows are gathered by vectorized indexing straight from the ref,
+so the ``(Q, c_comp, d)`` gathered block never materializes as an
+intermediate between stages.
 
-* **interpret** (the off-TPU production + CI path): ``grid=(1,)`` with the
-  whole chunk resident; ``data`` is handed over in ``pltpu.ANY`` memory
-  space and candidate rows are gathered by vectorized indexing straight
-  from the ref — the interpreter's analogue of the DMA schedule below, with
-  no per-step block copies.
-* **compiled** (Mosaic, real TPU): ``grid=(Q,)`` — one query row per step;
-  the compacted indices stay VMEM-resident while candidate vectors stream
-  HBM->VMEM through a two-slot ``(C_BLK, D_PAD)`` ring buffer of per-row
-  async copies (``pltpu.make_async_copy`` + DMA semaphores), chunk ``t+1``
-  in flight while chunk ``t``'s distances merge into the running top-k.
-  Written to the TPU guide's double-buffering pattern; this container has
-  no TPU, so the schedule is exercised only through the shared-body
-  interpret tests.
+Compiled on a TPU, Mosaic refuses the fused body (:data:`XLA_STAGES`). The
+f32 tail then runs as the pipeline's staged stages 3-5, whose stage 5 is
+the ``l1_topk`` kernel (``core/pipeline.py:_pallas_ops``); the
+compressed-payload tail, which has no staged twin, runs as XLA ops around
+the ``l1_topk`` kernel's distance mode (:func:`_tail_payload_compiled`).
 
-Both reproduce the §6 lowest-position tie rule: compacted rows ascend by
-global index and ``lax.top_k`` prefers earlier positions on equal
-distances, exactly like the staged reference tail.
+Every formulation reproduces the §6 lowest-position tie rule: compacted
+rows ascend by global index and the top-k prefers earlier positions on
+equal distances, exactly like the staged reference tail.
 """
 from __future__ import annotations
 
@@ -36,7 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.l1_topk import ops as l1_ops
 
 # Kernel-internal sentinel: a plain int (kernels cannot capture array
 # constants), equal to pipeline._IDX_SENTINEL — sorts after any real index.
@@ -44,8 +40,27 @@ _SENT = jnp.iinfo(jnp.int32).max
 
 _CUMSUM_BLK = 16  # prefix-sum block: one triangular-matmul tile
 
+# What runs as XLA ops instead of Mosaic on a TPU, each with the refusal
+# that keeps it there (reported by chip_smoke.py and CHANGES.md).
+XLA_STAGES = (
+    "query tail stages 3-4 (run merge, dedup, compaction): the fused body"
+    " is refused ('NotImplementedError: Only 2D gather is supported' — its"
+    " searchsorted and take_along_axis compaction); the f32 tail runs the"
+    " pipeline's staged sort dedup and sort compaction, the payload tail"
+    " this module's merge-network dedup and compaction",
+    "query tail candidate-row gather: a per-row DMA of a d=30 f32 row is"
+    " refused ('Slice shape along dimension 1 must be aligned to tiling"
+    " (128), but is 30'), and one-row DMAs of f16/i8 rows are refused"
+    " ('... dimension 0 must be aligned to tiling (8), but is 1')",
+    "compressed-payload tail: the c_rerank shortlist and the final top-k"
+    " are XLA top_k: Mosaic refuses lax.top_k ('Unimplemented primitive in"
+    " Pallas TPU lowering: top_k'), and the l1_topk kernel's replacement,"
+    " smallest_k, would take c_rerank=128 serial min-reduce rounds for the"
+    " shortlist (not measured on the chip)",
+)
 
-def merge_sorted_runs(x: jax.Array, run: int, q_major: bool = False) -> jax.Array:
+
+def merge_sorted_runs(x: jax.Array, run: int) -> jax.Array:
     """Merge each row's ascending length-``run`` runs into one sorted row.
 
     ``x (Q, C)`` with ``C = R * run`` and R a power of two; every
@@ -57,51 +72,30 @@ def merge_sorted_runs(x: jax.Array, run: int, q_major: bool = False) -> jax.Arra
     the megakernel's stage-3 replacement and is exact: the output is a
     permutation of ``x`` per row, sorted ascending.
 
-    ``q_major`` runs the identical network on the transposed ``(C, Q)``
-    layout, keeping the query axis innermost: the network's late substages
-    compare stride-``2^j`` element pairs, which degenerates to scalar code
-    row-major but stays a dense vector op over the whole chunk when each
-    compare spans ``Q`` contiguous lanes. The interpret (whole-chunk) body
-    uses it; the compiled body's grid step sees one query row (Q=1), where
-    the transpose buys nothing and lane-major stays right.
+    The network runs on the transposed ``(C, Q)`` layout, keeping the query
+    axis innermost: its late substages compare stride-``2^j`` element
+    pairs, which degenerates to scalar code row-major but stays a dense
+    vector op over the whole chunk when each compare spans ``Q`` contiguous
+    lanes.
     """
     q_n, c = x.shape
     r, width = c // run, run
-    if q_major:
-        y = x.T.reshape(r, width, q_n)
-        while r > 1:
-            a = y[0::2]
-            b = y[1::2][:, ::-1, :]  # descending half -> bitonic pair
-            z = jnp.concatenate([a, b], axis=1)  # (r//2, 2*width, Q)
-            width *= 2
-            dd = width // 2
-            while dd >= 1:  # bitonic merge network, Q innermost
-                w = z.reshape(-1, 2, dd, q_n)
-                lo = jnp.minimum(w[:, 0], w[:, 1])
-                hi = jnp.maximum(w[:, 0], w[:, 1])
-                z = jnp.stack([lo, hi], axis=1).reshape(-1, width, q_n)
-                dd //= 2
-            y = z
-            r //= 2
-        return y.reshape(c, q_n).T
-    x = x.reshape(q_n, r, width)
+    y = x.T.reshape(r, width, q_n)
     while r > 1:
-        a = x[:, 0::2, :]
-        b = x[:, 1::2, :][:, :, ::-1]  # descending half -> bitonic pair
-        y = jnp.concatenate([a, b], axis=-1)
+        a = y[0::2]
+        b = y[1::2][:, ::-1, :]  # descending half -> bitonic pair
+        z = jnp.concatenate([a, b], axis=1)  # (r//2, 2*width, Q)
         width *= 2
         dd = width // 2
-        while dd >= 1:  # bitonic merge network on (r//2) sequences
-            z = y.reshape(q_n, -1, 2, dd)
-            lo = jnp.minimum(z[:, :, 0, :], z[:, :, 1, :])
-            hi = jnp.maximum(z[:, :, 0, :], z[:, :, 1, :])
-            y = jnp.concatenate(
-                [lo[:, :, None, :], hi[:, :, None, :]], axis=2
-            ).reshape(q_n, r // 2, width)
+        while dd >= 1:  # bitonic merge network, Q innermost
+            w = z.reshape(-1, 2, dd, q_n)
+            lo = jnp.minimum(w[:, 0], w[:, 1])
+            hi = jnp.maximum(w[:, 0], w[:, 1])
+            z = jnp.stack([lo, hi], axis=1).reshape(-1, width, q_n)
             dd //= 2
-        x = y
+        y = z
         r //= 2
-    return x[:, 0]
+    return y.reshape(c, q_n).T
 
 
 def _prefix_sum(u: jax.Array) -> jax.Array:
@@ -133,9 +127,9 @@ def _prefix_sum(u: jax.Array) -> jax.Array:
 
 
 def _dedup_compact(
-    cand: jax.Array, run: int, c_comp: int, q_major: bool = False
+    cand: jax.Array, run: int, c_comp: int
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused stages 3+4 on raw candidate rows (shared by both kernel bodies).
+    """Fused stages 3+4 on raw candidate rows (shared by every tail body).
 
     Returns ``comp (Q, c_comp)`` — each row's unique candidate indices
     ascending, :data:`_SENT` beyond the survivor count — and
@@ -145,7 +139,7 @@ def _dedup_compact(
     full-width sort.
     """
     x = jnp.where(cand < 0, _SENT, cand)
-    srt = merge_sorted_runs(x, run, q_major=q_major)
+    srt = merge_sorted_runs(x, run)
     uniq = jnp.concatenate(
         [srt[:, :1] < _SENT, srt[:, 1:] != srt[:, :-1]], axis=-1
     ) & (srt < _SENT)
@@ -182,13 +176,12 @@ def _tail_kernel_interpret(
 ):
     """Whole-chunk megakernel body (interpret formulation).
 
-    ``data_ref`` lives in ``pltpu.ANY`` space: the candidate gather indexes
-    it directly, so no block copy of the dataset ever happens — the
-    interpreter's stand-in for the compiled path's DMA ring.
+    ``data_ref`` lives in ``pl.ANY`` space: the candidate gather indexes
+    it directly, so no block copy of the dataset ever happens.
     """
     cand = cand_ref[...]
     qs = q_ref[...]
-    comp, comparisons = _dedup_compact(cand, run, c_comp, q_major=True)
+    comp, comparisons = _dedup_compact(cand, run, c_comp)
     valid = comp != _SENT
     safe = jnp.clip(jnp.where(valid, comp, 0), 0, n - 1)
     pts = data_ref[safe]  # (Q, c_comp, d) — the one HBM touch per candidate
@@ -199,73 +192,38 @@ def _tail_kernel_interpret(
     ovf_ref[...] = jnp.maximum(comparisons - jnp.int32(c_comp), 0)
 
 
-def _tail_kernel_dma(
-    q_ref, cand_ref, data_ref, kd_ref, ki_ref, cmp_ref, ovf_ref,
-    buf_ref, sem_ref,
-    *, run: int, c_comp: int, k: int, n: int, c_blk: int,
+def _tail_payload_compiled(
+    data, qdata, meta, queries, cand, *, run, c_comp, c_rerank, k,
+    interpret=False,
 ):
-    """Per-query megakernel body (compiled Mosaic formulation).
+    """The compressed-payload tail's compiled formulation -> ``(kd, ki,
+    comparisons, overflow, rerank_misses)``.
 
-    Grid step = one query row. The compacted indices stay VMEM-resident;
-    candidate vectors stream through ``buf_ref`` — a two-slot
-    ``(C_BLK, D_PAD)`` ring (scratch VMEM) filled by per-row async copies
-    from HBM with one DMA semaphore per (slot, row). Chunk ``t+1``'s copies
-    start before chunk ``t``'s distances are reduced, hiding gather latency
-    behind the L1/top-k compute (the guide's double-buffering pattern).
+    Dedup, compaction and the gathers run as XLA ops, for the Mosaic
+    refusals listed in :data:`XLA_STAGES`; the approximate and the exact
+    distance passes run in the ``l1_topk`` kernel's distance mode. The
+    gathers read the quantized rows (dequantized by XLA in the gather's
+    fusion) and the f32 shortlist rows, and the shortlist and final top-k
+    are XLA ``top_k``. ``interpret=True`` runs the same kernel through the
+    Pallas interpreter (tests).
     """
-    comp, comparisons = _dedup_compact(cand_ref[...], run, c_comp)
+    comp, comparisons = _dedup_compact(cand, run, c_comp)
     valid = comp != _SENT
-    safe = jnp.clip(jnp.where(valid, comp, 0), 0, n - 1)
-    qrow = q_ref[...]  # (1, D_PAD)
-    n_chunks = c_comp // c_blk
-
-    def copy_row(slot, t, j):
-        return pltpu.make_async_copy(
-            data_ref.at[pl.ds(safe[0, t * c_blk + j], 1), :],
-            buf_ref.at[slot, pl.ds(j, 1), :],
-            sem_ref.at[slot, j],
-        )
-
-    def start_chunk(slot, t):
-        def issue(j, carry):
-            copy_row(slot, t, j).start()
-            return carry
-
-        jax.lax.fori_loop(0, c_blk, issue, 0)
-
-    start_chunk(0, 0)
-
-    def step(t, carry):
-        best_d, best_i = carry  # running (1, k) top-k
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < n_chunks)
-        def _():
-            start_chunk(1 - slot, t + 1)
-
-        def wait(j, carry2):
-            copy_row(slot, t, j).wait()
-            return carry2
-
-        jax.lax.fori_loop(0, c_blk, wait, 0)
-        tile = buf_ref[slot]  # (C_BLK, D_PAD)
-        dist = jnp.sum(jnp.abs(tile - qrow), axis=-1)[None, :]  # (1, C_BLK)
-        sl = jax.lax.dynamic_slice_in_dim(comp, t * c_blk, c_blk, axis=1)
-        ok = jax.lax.dynamic_slice_in_dim(valid, t * c_blk, c_blk, axis=1)
-        dist = jnp.where(ok, dist, jnp.inf)
-        # merge into the running top-k; earlier (lower-position) candidates
-        # come first in the concat, so ties keep the §6 lowest-position rule
-        cat_d = jnp.concatenate([best_d, dist], axis=1)
-        cat_i = jnp.concatenate([best_i, jnp.where(ok, sl, -1)], axis=1)
-        neg, p = jax.lax.top_k(-cat_d, k)
-        return -neg, jnp.take_along_axis(cat_i, p, axis=1)
-
-    init = (jnp.full((1, k), jnp.inf), jnp.full((1, k), -1, jnp.int32))
-    best_d, best_i = jax.lax.fori_loop(0, n_chunks, step, init)
-    kd_ref[...] = best_d
-    ki_ref[...] = jnp.where(jnp.isfinite(best_d), best_i, -1)
-    cmp_ref[...] = comparisons
-    ovf_ref[...] = jnp.maximum(comparisons - jnp.int32(c_comp), 0)
+    idx = jnp.where(valid, comp, 0)
+    mrows = meta[idx]  # (Q, cc, 2) [scale, error bound]
+    deq = qdata[idx].astype(jnp.float32) * mrows[..., 0:1]
+    ad = l1_ops.l1_dist(queries, deq, valid, interpret=interpret)
+    _, spos = jax.lax.top_k(-ad, c_rerank)  # ties -> lowest compacted position
+    scand = jnp.take_along_axis(comp, spos, axis=1)
+    svalid = jnp.take_along_axis(valid, spos, axis=1)
+    ed = l1_ops.l1_dist(
+        queries, data[jnp.where(svalid, scand, 0)], svalid, interpret=interpret
+    )
+    kd, ki, misses = _payload_finish(
+        comp, valid, ad, mrows[..., 1], ed, spos, svalid, c_rerank, k
+    )
+    overflow = jnp.maximum(comparisons - jnp.int32(c_comp), 0)
+    return kd, ki, comparisons, overflow, misses
 
 
 def _payload_finish(
@@ -300,14 +258,14 @@ def _tail_kernel_payload_interpret(
 ):
     """Whole-chunk compressed-payload megakernel body (interpret).
 
-    ``qd_ref``/``meta_ref``/``data_ref`` live in ``pltpu.ANY`` space: the
+    ``qd_ref``/``meta_ref``/``data_ref`` live in ``pl.ANY`` space: the
     candidate gather streams *quantized* rows (the compressed HBM touch),
     and only the ``c_rerank`` shortlist rows are re-gathered from the f32
     dataset for the exact rerank (DESIGN.md §13).
     """
     cand = cand_ref[...]
     qs = q_ref[...]
-    comp, comparisons = _dedup_compact(cand, run, c_comp, q_major=True)
+    comp, comparisons = _dedup_compact(cand, run, c_comp)
     valid = comp != _SENT
     safe = jnp.clip(jnp.where(valid, comp, 0), 0, n - 1)
     mrows = meta_ref[safe]  # (Q, cc, 2)
@@ -330,117 +288,7 @@ def _tail_kernel_payload_interpret(
     mis_ref[...] = misses
 
 
-def _tail_kernel_payload_dma(
-    q_ref, cand_ref, data_ref, qd_ref, meta_ref,
-    kd_ref, ki_ref, cmp_ref, ovf_ref, mis_ref,
-    buf_ref, mbuf_ref, ebuf_ref, ad_ref, qe_ref, sem_ref, msem_ref, esem_ref,
-    *, run: int, c_comp: int, c_rerank: int, k: int, n: int, c_blk: int,
-):
-    """Per-query compressed-payload megakernel body (compiled Mosaic).
-
-    Same two-slot ring schedule as :func:`_tail_kernel_dma`, but the ring
-    streams *quantized* rows (``buf_ref``, half/quarter bytes) plus their
-    (scale, error) meta pairs (``mbuf_ref``); approximate distances and
-    error bounds accumulate in VMEM (``ad_ref``/``qe_ref`` — f32 rows of
-    the full compacted width, small enough to stay resident). After the
-    stream, the ``c_rerank`` shortlist is selected in-VMEM, its exact f32
-    rows gathered through one more burst of per-row copies (``ebuf_ref``),
-    and the shared epilogue finishes the position-ordered exact top-k and
-    the miss count. As with the base compiled body, this container has no
-    TPU — the schedule is exercised through the shared-logic interpret
-    tests.
-    """
-    comp, comparisons = _dedup_compact(cand_ref[...], run, c_comp)
-    valid = comp != _SENT
-    safe = jnp.clip(jnp.where(valid, comp, 0), 0, n - 1)
-    qrow = q_ref[...]  # (1, D)
-    n_chunks = c_comp // c_blk
-
-    def copy_row(slot, t, j):
-        return pltpu.make_async_copy(
-            qd_ref.at[pl.ds(safe[0, t * c_blk + j], 1), :],
-            buf_ref.at[slot, pl.ds(j, 1), :],
-            sem_ref.at[slot, j],
-        )
-
-    def copy_meta(slot, t, j):
-        return pltpu.make_async_copy(
-            meta_ref.at[pl.ds(safe[0, t * c_blk + j], 1), :],
-            mbuf_ref.at[slot, pl.ds(j, 1), :],
-            msem_ref.at[slot, j],
-        )
-
-    def start_chunk(slot, t):
-        def issue(j, carry):
-            copy_row(slot, t, j).start()
-            copy_meta(slot, t, j).start()
-            return carry
-
-        jax.lax.fori_loop(0, c_blk, issue, 0)
-
-    start_chunk(0, 0)
-
-    def step(t, carry):
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < n_chunks)
-        def _():
-            start_chunk(1 - slot, t + 1)
-
-        def wait(j, carry2):
-            copy_row(slot, t, j).wait()
-            copy_meta(slot, t, j).wait()
-            return carry2
-
-        jax.lax.fori_loop(0, c_blk, wait, 0)
-        mtile = mbuf_ref[slot]  # (C_BLK, 2)
-        deq = buf_ref[slot].astype(jnp.float32) * mtile[:, 0:1]
-        dist = jnp.sum(jnp.abs(deq - qrow), axis=-1)  # (C_BLK,)
-        ad_ref[0, pl.ds(t * c_blk, c_blk)] = dist
-        qe_ref[0, pl.ds(t * c_blk, c_blk)] = mtile[:, 1]
-        return carry
-
-    jax.lax.fori_loop(0, n_chunks, step, 0)
-
-    ad = jnp.where(valid, ad_ref[...], jnp.inf)  # (1, c_comp)
-    _, spos = jax.lax.top_k(-ad, c_rerank)
-    scand = jnp.take_along_axis(comp, spos, axis=1)
-    svalid = jnp.take_along_axis(valid, spos, axis=1)
-    ssafe = jnp.clip(jnp.where(svalid, scand, 0), 0, n - 1)
-
-    def issue_exact(j, carry):
-        pltpu.make_async_copy(
-            data_ref.at[pl.ds(ssafe[0, j], 1), :],
-            ebuf_ref.at[pl.ds(j, 1), :],
-            esem_ref.at[j],
-        ).start()
-        return carry
-
-    jax.lax.fori_loop(0, c_rerank, issue_exact, 0)
-
-    def wait_exact(j, carry):
-        pltpu.make_async_copy(
-            data_ref.at[pl.ds(ssafe[0, j], 1), :],
-            ebuf_ref.at[pl.ds(j, 1), :],
-            esem_ref.at[j],
-        ).wait()
-        return carry
-
-    jax.lax.fori_loop(0, c_rerank, wait_exact, 0)
-    ed = jnp.sum(jnp.abs(ebuf_ref[...] - qrow), axis=-1)[None, :]  # (1, cr)
-    ed = jnp.where(svalid, ed, jnp.inf)
-    kd, ki, misses = _payload_finish(
-        comp, valid, ad, qe_ref[...], ed, spos, svalid, c_rerank, k
-    )
-    kd_ref[...], ki_ref[...] = kd, ki
-    cmp_ref[...] = comparisons
-    ovf_ref[...] = jnp.maximum(comparisons - jnp.int32(c_comp), 0)
-    mis_ref[...] = misses
-
-
-@functools.partial(
-    jax.jit, static_argnames=("run", "c_comp", "k", "interpret", "c_blk")
-)
+@functools.partial(jax.jit, static_argnames=("run", "c_comp", "k"))
 def query_tail_pallas(
     data: jax.Array,  # (n, d)
     queries: jax.Array,  # (Q, d)
@@ -449,63 +297,31 @@ def query_tail_pallas(
     run: int,
     c_comp: int,
     k: int,
-    interpret: bool = True,
-    c_blk: int = 128,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Launch the fused tail -> ``(kd, ki, comparisons, overflow)``.
+    """Launch the fused tail (interpreted) -> ``(kd, ki, comparisons,
+    overflow)``.
 
     Callers go through :func:`repro.kernels.query_fused.ops.query_tail`,
-    which pads ``cand`` to the power-of-two run count this launch requires
-    and resolves the interpret policy.
+    which pads ``cand`` to the power-of-two run count this launch requires.
     """
     q_n, c = cand.shape
     n, d = data.shape
-    if interpret:
-        kern = functools.partial(
-            _tail_kernel_interpret, run=run, c_comp=c_comp, k=k, n=n
-        )
-        return pl.pallas_call(
-            kern,
-            grid=(1,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # data stays HBM-side
-                pl.BlockSpec((q_n, d), lambda i: (0, 0)),
-                pl.BlockSpec((q_n, c), lambda i: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((q_n, k), lambda i: (0, 0)),
-                pl.BlockSpec((q_n, k), lambda i: (0, 0)),
-                pl.BlockSpec((q_n,), lambda i: (0,)),
-                pl.BlockSpec((q_n,), lambda i: (0,)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((q_n, k), jnp.float32),
-                jax.ShapeDtypeStruct((q_n, k), jnp.int32),
-                jax.ShapeDtypeStruct((q_n,), jnp.int32),
-                jax.ShapeDtypeStruct((q_n,), jnp.int32),
-            ],
-            interpret=True,
-        )(data, queries, cand)
-
-    c_blk = max(1, min(c_blk, c_comp))
-    while c_comp % c_blk:  # ring chunks must tile the compacted width
-        c_blk //= 2
     kern = functools.partial(
-        _tail_kernel_dma, run=run, c_comp=c_comp, k=k, n=n, c_blk=c_blk
+        _tail_kernel_interpret, run=run, c_comp=c_comp, k=k, n=n
     )
     return pl.pallas_call(
         kern,
-        grid=(q_n,),
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # data: DMA'd row by row
+            pl.BlockSpec(memory_space=pl.ANY),  # data stays HBM-side
+            pl.BlockSpec((q_n, d), lambda i: (0, 0)),
+            pl.BlockSpec((q_n, c), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec((q_n, k), lambda i: (0, 0)),
+            pl.BlockSpec((q_n, k), lambda i: (0, 0)),
+            pl.BlockSpec((q_n,), lambda i: (0,)),
+            pl.BlockSpec((q_n,), lambda i: (0,)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((q_n, k), jnp.float32),
@@ -513,17 +329,13 @@ def query_tail_pallas(
             jax.ShapeDtypeStruct((q_n,), jnp.int32),
             jax.ShapeDtypeStruct((q_n,), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, c_blk, d), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, c_blk)),
-        ],
-        interpret=False,
-    )(queries, cand, data)
+        interpret=True,
+    )(data, queries, cand)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("run", "c_comp", "c_rerank", "k", "interpret", "c_blk"),
+    static_argnames=("run", "c_comp", "c_rerank", "k", "interpret"),
 )
 def query_tail_payload_pallas(
     data: jax.Array,  # (n, d) exact f32 rows (rerank only)
@@ -537,7 +349,6 @@ def query_tail_payload_pallas(
     c_rerank: int,
     k: int,
     interpret: bool = True,
-    c_blk: int = 128,
 ) -> tuple[jax.Array, ...]:
     """Launch the compressed-payload fused tail (DESIGN.md §13).
 
@@ -565,9 +376,9 @@ def query_tail_payload_pallas(
             kern,
             grid=(1,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # data: rerank gather
-                pl.BlockSpec(memory_space=pltpu.ANY),  # qdata: compressed rows
-                pl.BlockSpec(memory_space=pltpu.ANY),  # meta: scale + err
+                pl.BlockSpec(memory_space=pl.ANY),  # data: rerank gather
+                pl.BlockSpec(memory_space=pl.ANY),  # qdata: compressed rows
+                pl.BlockSpec(memory_space=pl.ANY),  # meta: scale + err
                 pl.BlockSpec((q_n, d), lambda i: (0, 0)),
                 pl.BlockSpec((q_n, c), lambda i: (0, 0)),
             ],
@@ -582,40 +393,7 @@ def query_tail_payload_pallas(
             interpret=True,
         )(data, qdata, meta, queries, cand)
 
-    c_blk = max(1, min(c_blk, c_comp))
-    while c_comp % c_blk:  # ring chunks must tile the compacted width
-        c_blk //= 2
-    kern = functools.partial(
-        _tail_kernel_payload_dma,
-        run=run, c_comp=c_comp, c_rerank=cr, k=k, n=n, c_blk=c_blk,
+    return _tail_payload_compiled(
+        data, qdata, meta, queries, cand, run=run, c_comp=c_comp,
+        c_rerank=cr, k=k,
     )
-    return pl.pallas_call(
-        kern,
-        grid=(q_n,),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # data: shortlist DMA
-            pl.BlockSpec(memory_space=pltpu.ANY),  # qdata: ring DMA
-            pl.BlockSpec(memory_space=pltpu.ANY),  # meta: ring DMA
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((2, c_blk, d), qdata.dtype),
-            pltpu.VMEM((2, c_blk, 2), jnp.float32),
-            pltpu.VMEM((cr, d), jnp.float32),
-            pltpu.VMEM((1, c_comp), jnp.float32),
-            pltpu.VMEM((1, c_comp), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, c_blk)),
-            pltpu.SemaphoreType.DMA((2, c_blk)),
-            pltpu.SemaphoreType.DMA((cr,)),
-        ],
-        interpret=False,
-    )(queries, cand, data, qdata, meta)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from repro import configs, obs
 from repro.launch.mesh import make_production_mesh
 from repro.models import api
-from repro.runtime.compat import cost_analysis_dict
 from repro.optim import adamw
 from repro.sharding import ctx
 from repro.train import loop as train_loop
@@ -127,7 +126,7 @@ def run_cell(arch_id: str, cell: str, multi_pod: bool, out_dir: str) -> dict:
             with obs.timed_section("dryrun.compile") as compile_sec:
                 compiled = lowered.compile()
             mem = compiled.memory_analysis()
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             hlo = compiled.as_text()
             coll = collective_bytes(hlo)
         rec.update(

@@ -5,24 +5,21 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kwargs(n: int) -> dict:
-    # jax >= 0.6 wants explicit Auto axis types; 0.4.x has no AxisType.
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips with a leading pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small host-device mesh for tests/examples (requires enough devices)."""
     return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_types_kwargs(2)
+        (data, model), ("data", "model"), axis_types=_auto(2)
     )
 
 
@@ -33,5 +30,5 @@ def make_replicated_mesh(rep: int = 1, data: int = 1, model: int = 1):
     times, and ``distributed.mesh_query`` row-shards the query batch over
     the ``rep`` axis before its two-stage merge (DESIGN.md §10)."""
     return jax.make_mesh(
-        (rep, data, model), ("rep", "data", "model"), **_axis_types_kwargs(3)
+        (rep, data, model), ("rep", "data", "model"), axis_types=_auto(3)
     )

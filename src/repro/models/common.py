@@ -194,9 +194,9 @@ def decode_attention_cp(
         return out.reshape(b_loc, 1, hq, dh).astype(q.dtype)
 
     cur_b = jnp.broadcast_to(cur_len, (b,))
-    return _ctx.shard_map(
+    return jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(
             P(bspec, None, None, None),
             P(bspec, axis, None, None),
@@ -204,6 +204,7 @@ def decode_attention_cp(
             P(bspec),
         ),
         out_specs=P(bspec, None, None, None),
+        check_vma=False,
     )(q, k_cache, v_cache, cur_b)
 
 

@@ -202,11 +202,12 @@ def moe_apply(p, x, cfg):
         in_p["e_gate"] = P(axis, None, None)
         in_p["e_up"] = P(axis, None, None)
         in_p["e_down"] = P(axis, None, None)
-        out, aux = ctx.shard_map(
+        out, aux = jax.shard_map(
             lambda pp, xx: _moe_a2a_body(pp, xx, cfg, axis, ep, e_loc),
-            mesh,
+            mesh=mesh,
             in_specs=(in_p, P(batch_axes if batch_axes else None, axis, None)),
             out_specs=(P(batch_axes if batch_axes else None, axis, None), P()),
+            check_vma=False,
         )(p, x.astype(BF16))
         return out.astype(x.dtype), aux
     e_loc = cfg.n_experts // ep
@@ -230,11 +231,12 @@ def moe_apply(p, x, cfg):
     in_p["e_gate"] = P(axis, None, None)
     in_p["e_up"] = P(axis, None, None)
     in_p["e_down"] = P(axis, None, None)
-    out, aux = ctx.shard_map(
+    out, aux = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(in_p, P(batch_axes if batch_axes else None, axis, None)),
         out_specs=(P(batch_axes if batch_axes else None, axis, None), P()),
+        check_vma=False,
     )(p, x.astype(BF16))
     return out.astype(x.dtype), aux
 

@@ -208,6 +208,7 @@ def retraces(stage: str) -> int:
 QUERY_STAGES: tuple[str, ...] = (
     "single_query",
     "grid_query",
+    "mesh_query",
     "stream_query",
     "hash",
     "gather_work",

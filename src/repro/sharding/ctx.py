@@ -116,21 +116,3 @@ def mesh_axis_size(*axes_names: str) -> int:
     if mesh is None:
         return 1
     return math.prod(mesh.shape.get(a, 1) for a in axes_names)
-
-
-def shard_map(body, mesh: Mesh, in_specs, out_specs):
-    """Version-portable shard_map without replication checking.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (``check_vma``); jax 0.4.x has the
-    experimental API (``check_rep``). All SPMD code in the repo routes
-    through this one helper.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )

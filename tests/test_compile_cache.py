@@ -95,3 +95,31 @@ def test_reference_backend_never_touches_fused_kernel():
     jax.block_until_ready(res.knn_idx)
     assert jnp.all(res.comparisons >= 0)
     assert obs.retraces("query_tail") == before
+
+
+def test_mesh_query_steady_state_no_retrace():
+    """A mesh deployment compiles one query program per (reducer,
+    max_cells): the index, data, queries and route plan enter it as
+    arguments, so repeated queries — new values, same batch shape —
+    retrace nothing (the grid path's ``Index._grid_fn`` contract)."""
+    from repro.launch.mesh import make_local_mesh
+
+    cfg = _cfg()
+    data = jax.random.uniform(jax.random.PRNGKey(9), (256, 16))
+    q = jax.random.uniform(jax.random.PRNGKey(10), (16, 16))
+    for routed in (False, True):
+        deploy = dslsh.mesh(make_local_mesh(1, 1), routed=routed)
+        idx = dslsh.build(jax.random.PRNGKey(11), data, cfg, deploy)
+        first = idx.query(q)
+        jax.block_until_ready(first.knn_idx)
+        before = obs.retraces("mesh_query")
+        assert before >= 1
+        for qs in (q, q[::-1], q * 0.5):
+            jax.block_until_ready(idx.query(qs).knn_idx)
+        assert obs.retraces("mesh_query") == before, (
+            f"mesh query re-traced: {obs.retraces('mesh_query') - before}"
+            f" extra trace(s) (routed={routed})"
+        )
+        np.testing.assert_array_equal(
+            np.asarray(idx.query(q).knn_idx), np.asarray(first.knn_idx)
+        )

@@ -67,7 +67,7 @@ def test_chunked_build_bit_exact(n, chunk, backend):
 
 def test_chunked_build_traced_bit_exact():
     """Under an outer jit (simulate_build's cell programs) the chunked
-    builder traces the same ladder in-graph and stays bit-exact."""
+    mode sorts each table whole in-graph and stays bit-exact."""
     cfg = _cfg(build_chunk=48, build_mode="chunked")
     data = _data(150)
     mono = slsh.build_index(

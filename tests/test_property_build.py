@@ -41,6 +41,14 @@ def test_chunked_build_property(n, l_out, chunk, backend, seed):
         jax.random.normal(jax.random.PRNGKey(seed), (n, 7)) * 20 + 80
     )
     cfg = _cfg(chunk, backend, l_out)
+    if n == 0:  # an index over no points is a configuration error
+        for mode in ("monolithic", "chunked"):
+            with pytest.raises(pipeline.ConfigError, match="zero points"):
+                slsh.build_index(
+                    jax.random.PRNGKey(seed + 1), data,
+                    cfg.replace(build_mode=mode),
+                )
+        return
     mono = slsh.build_index(
         jax.random.PRNGKey(seed + 1), data, cfg.replace(build_mode="monolithic")
     )
@@ -57,7 +65,8 @@ def test_chunked_build_property(n, l_out, chunk, backend, seed):
 @settings(max_examples=8, deadline=None)
 def test_chunked_build_traced_property(n, chunk, seed):
     """Under an outer jit (simulate_build's vmapped cell programs) the
-    in-trace ladder stays bit-exact with the eager monolithic oracle."""
+    in-trace chunked build stays bit-exact with the eager monolithic
+    oracle."""
     data = jax.random.normal(jax.random.PRNGKey(seed), (n, 5)) * 20 + 80
     cfg = _cfg(chunk, "reference", 4)
     mono = slsh.build_index(

@@ -58,7 +58,8 @@ def _query_setup(draw):
     n_stream = draw(st.integers(0, 24))
     backend = draw(st.sampled_from(["reference", "pallas"]))
     use_inner = draw(st.booleans())
-    c_comp = draw(st.integers(1, 48))
+    # the validator accepts c_comp >= k (k=4 below) or c_comp <= 0 (off)
+    c_comp = draw(st.one_of(st.integers(4, 48), st.integers(-2, 0)))
     return seed, n, n_stream, backend, use_inner, c_comp
 
 

@@ -12,6 +12,7 @@ from repro.kernels.hash_pack import ref as hp_ref
 from repro.kernels.l1_topk import ops as l1_ops
 from repro.kernels.l1_topk import ref as l1_ref
 from repro.kernels.query_fused import ops as qf_ops
+from repro.kernels.query_fused import query_fused as qf
 from repro.kernels.query_fused import ref as qf_ref
 
 jax.config.update("jax_platform_name", "cpu")
@@ -139,9 +140,21 @@ def test_query_tail_payload_property(
     got = qf_ops.query_tail_payload(
         data, p.qdata, p.meta, qs, cand, run=run, c_comp=cc, c_rerank=cr, k=k
     )
+    # the compiled formulation (XLA stages around the l1_topk kernel), its
+    # kernel interpreted, on cand padded as query_fused.ops pads it
+    c_pad = qf_ops._run_padded_width(cand.shape[1], run)
+    compiled = jax.jit(
+        qf._tail_payload_compiled,
+        static_argnames=("run", "c_comp", "c_rerank", "k", "interpret"),
+    )(
+        data, p.qdata, p.meta, qs,
+        jnp.pad(cand, ((0, 0), (0, c_pad - cand.shape[1])), constant_values=-1),
+        run=run, c_comp=cc, c_rerank=min(cr, cc), k=k, interpret=True,
+    )
     names = ("kd", "ki", "comparisons", "overflow", "rerank_misses")
-    for g, w, name in zip(got, want, names):
+    for g, c, w, name in zip(got, compiled, want, names):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=name)
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(w), err_msg=name)
     f32 = qf_ref.query_tail_ref(data, qs, cand, c_comp=cc, k=k)
     misses = np.asarray(got[4])
     for row in range(q_n):

@@ -37,9 +37,7 @@ def _single_layer_flops_hlo(cfg, batch, seq):
         return dense.block_train(cfg, lp, x, jnp.arange(seq))
 
     compiled = jax.jit(f).lower(lp, x).compile()
-    from repro.runtime.compat import cost_analysis_dict
-
-    return float(cost_analysis_dict(compiled)["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 @pytest.mark.parametrize("arch_id", ["granite-8b", "qwen3-32b"])

@@ -229,12 +229,9 @@ def test_logical_to_spec_divisibility_fallback():
     import os
     from jax.sharding import PartitionSpec as P
 
-    kwargs = (
-        {"axis_types": (jax.sharding.AxisType.Auto,)}
-        if hasattr(jax.sharding, "AxisType")
-        else {}
+    mesh = jax.make_mesh(
+        (1,), ("model",), axis_types=(jax.sharding.AxisType.Auto,)
     )
-    mesh = jax.make_mesh((1,), ("model",), **kwargs)
     rules = ctx.ShardingRules()
     # 25 heads on a 1-way axis: always fine (size 1 divides)
     spec = ctx.logical_to_spec(mesh, rules, ("tensor", None), (25, 4))
