@@ -1,0 +1,7 @@
+"""Query rows answered inside the window, over the window's length."""
+
+
+def read(run):
+    if run.window_s <= 0:
+        return None
+    return run.rows_done_in_window / run.window_s
