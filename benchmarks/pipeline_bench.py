@@ -302,7 +302,7 @@ def run():
     inst = api.wrap_single(
         idxs["pallas"], data, cfg.replace(backend="pallas"), obs=ob
     )
-    inst.query(q)  # per-stage spans: tracing runs the eager schedule
+    inst.query(q)  # index.query span; stage time lives in a profiler trace
     report["obs_artifacts"] = {
         "trace": ob.save_trace(os.path.join(art_dir, "obs_trace.json")),
         "metrics": ob.save_metrics(os.path.join(art_dir, "obs_metrics.json")),

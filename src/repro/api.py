@@ -407,10 +407,11 @@ class Index:
         degradation is flagged, never silent.
 
         With an obs bundle bound (``build(..., obs=...)``) or ambiently
-        activated, the call records an ``index.query`` span, syncs the
-        result, and feeds the query metrics (latency, comparisons,
-        overflow, routed_frac, per-cell routed load — DESIGN.md §12);
-        unbound handles take the bare fast path after one check.
+        activated, the call records an ``index.query`` span around the same
+        program an unbound handle runs; with metrics on, it also syncs the
+        result and feeds the query metrics (latency, comparisons, overflow,
+        routed_frac, per-cell routed load — DESIGN.md §12). Unbound handles
+        take the bare fast path after one check.
         """
         queries = jnp.asarray(queries)
         if budget is not None:
@@ -445,7 +446,10 @@ class Index:
                 queries=int(queries.shape[0]),
             ) as sp:
                 res = self._query_impl(queries, max_cells, drop_mask, drop_cells)
-                jax.block_until_ready(res)
+                if ob.metrics is not None:
+                    # the latency histogram is end to end; a trace alone
+                    # reads device time off the profiler (§12.1)
+                    jax.block_until_ready(res)
         if ob.metrics is not None:
             self._record_query_metrics(ob, res, sp.dur_s)
         return res
@@ -461,24 +465,6 @@ class Index:
                 "drop_mask only applies to grid/mesh deployments (a single"
                 " shard has no straggler nodes to drop)",
             )
-            ob = obs_mod.get_active()
-            if ob is not None and ob.tracing:
-                # per-stage spans need the eager per-stage schedule —
-                # run the pipeline outside the handle's one-jit wrapper
-                # (bit-identical; §12 sync-point policy)
-                res = pipeline.query_batch(
-                    self._state["index"], self._state["data"], queries,
-                    self.cfg, payload=self._payload(),
-                )
-                return DistributedQueryResult(
-                    res.knn_dist,
-                    res.knn_idx,
-                    res.comparisons[None, None],
-                    res.compaction_overflow[None, None],
-                    jnp.ones((1, 1, queries.shape[0]), bool),
-                    None if res.rerank_misses is None
-                    else res.rerank_misses[None, None],
-                )
             return self._single_fn()(queries)
         if kind == "grid":
             dm = (

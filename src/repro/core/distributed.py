@@ -346,26 +346,27 @@ def _mesh_query_fn(
         core = jax.lax.axis_index("model")
         n_loc = data_local.shape[0]
         res = cell_query(index_local, data_local, node * n_loc, qs, cfg, grid)
-        r_q = routedm[:, node, core]  # this cell's slice of the route mask
-        kd = jnp.where(r_q[:, None], res.knn_dist, jnp.inf)
-        ki = jnp.where(r_q[:, None], res.knn_idx, -1)
-        comps = jnp.where(r_q, res.comparisons, 0)
-        overflow = jnp.where(r_q, res.compaction_overflow, 0)
-        dropped = dropm[node]
-        kd = jnp.where(dropped, jnp.inf, kd)
-        ki = jnp.where(dropped, -1, ki)
-        # Master: merge within the node (over cores), then across nodes
-        if reducer == "tree":
-            kd, ki = merge_axis_tree("model", kd, ki, cfg.k, grid.p)
-            kd, ki = merge_axis_tree("data", kd, ki, cfg.k, grid.nu)
-        else:
-            kd, ki = merge_axis_allgather("model", kd, ki, cfg.k)
-            kd, ki = merge_axis_allgather("data", kd, ki, cfg.k)
-        if has_rep:
-            # stage 2 of the §10 merge: replicas own disjoint contiguous row
-            # blocks, so reassembly is a concat in rep order
-            kd = jax.lax.all_gather(kd, "rep").reshape(-1, kd.shape[-1])
-            ki = jax.lax.all_gather(ki, "rep").reshape(-1, ki.shape[-1])
+        with jax.named_scope("dslsh.merge"):
+            r_q = routedm[:, node, core]  # this cell's slice of the route mask
+            kd = jnp.where(r_q[:, None], res.knn_dist, jnp.inf)
+            ki = jnp.where(r_q[:, None], res.knn_idx, -1)
+            comps = jnp.where(r_q, res.comparisons, 0)
+            overflow = jnp.where(r_q, res.compaction_overflow, 0)
+            dropped = dropm[node]
+            kd = jnp.where(dropped, jnp.inf, kd)
+            ki = jnp.where(dropped, -1, ki)
+            # Master: merge within the node (over cores), then across nodes
+            if reducer == "tree":
+                kd, ki = merge_axis_tree("model", kd, ki, cfg.k, grid.p)
+                kd, ki = merge_axis_tree("data", kd, ki, cfg.k, grid.nu)
+            else:
+                kd, ki = merge_axis_allgather("model", kd, ki, cfg.k)
+                kd, ki = merge_axis_allgather("data", kd, ki, cfg.k)
+            if has_rep:
+                # stage 2 of the §10 merge: replicas own disjoint contiguous
+                # row blocks, so reassembly is a concat in rep order
+                kd = jax.lax.all_gather(kd, "rep").reshape(-1, kd.shape[-1])
+                ki = jax.lax.all_gather(ki, "rep").reshape(-1, ki.shape[-1])
         return kd, ki, comps[None, None], overflow[None, None]
 
     if has_rep:
@@ -387,10 +388,7 @@ def _mesh_query_fn(
         # tests/test_compile_cache.py reads it
         count_retrace("mesh_query")
         if occupancy is not None:
-            pk = routing.probe_keys(routing.family_from_index(index), queries, cfg)
-            routed, scores = routing.route_mask(occupancy, pk, grid)
-            if max_cells is not None:
-                routed = routing.apply_cell_budget(routed, scores, max_cells)
+            routed, _ = _route(index, occupancy, queries, cfg, grid, max_cells)
         else:
             routed = jnp.ones((queries.shape[0], grid.nu, grid.p), bool)
         qd, qi, comps, overflow = cells(index, data, queries, drop_mask, routed)
@@ -399,6 +397,19 @@ def _mesh_query_fn(
         )
 
     return jax.jit(run)
+
+
+def _route(index, occupancy, queries, cfg: slsh.SLSHConfig, grid: Grid,
+           max_cells: int | None):
+    """The §10 router inside a query program, under the ``dslsh.route``
+    scope: probe keys against the full family, the route mask (Q, nu, p)
+    and its landing scores, then the ``max_cells`` budget."""
+    with jax.named_scope("dslsh.route"):
+        pk = routing.probe_keys(routing.family_from_index(index), queries, cfg)
+        routed, scores = routing.route_mask(occupancy, pk, grid)
+        if max_cells is not None:
+            routed = routing.apply_cell_budget(routed, scores, max_cells)
+    return routed, scores
 
 
 def dslsh_query(
@@ -521,59 +532,58 @@ def grid_query(
     q = queries.shape[0]
 
     if plan is None:
-        kd = jnp.where(drop_mask[:, None, None, None], jnp.inf, res.knn_dist)
-        ki = jnp.where(drop_mask[:, None, None, None], -1, res.knn_idx)
-        comps, overflow = res.comparisons, res.compaction_overflow
-        visited = jnp.ones((grid.nu, grid.p, q), bool)
-        if drop_cells is not None:
-            dc = jnp.asarray(drop_cells)[:, :, None]  # (nu, p, 1) over Q
-            kd = jnp.where(dc[..., None], jnp.inf, kd)
-            ki = jnp.where(dc[..., None], -1, ki)
-            comps = jnp.where(dc, 0, comps)
-            overflow = jnp.where(dc, 0, overflow)
-            visited = visited & ~dc
-        kd = jnp.moveaxis(kd, 2, 0).reshape(q, -1)
-        ki = jnp.moveaxis(ki, 2, 0).reshape(q, -1)
-        fd, fi = jax.vmap(
-            lambda a, b: topk.masked_topk_smallest(a, b, cfg.k)
-        )(kd, ki)
+        with jax.named_scope("dslsh.merge"):
+            kd = jnp.where(drop_mask[:, None, None, None], jnp.inf, res.knn_dist)
+            ki = jnp.where(drop_mask[:, None, None, None], -1, res.knn_idx)
+            comps, overflow = res.comparisons, res.compaction_overflow
+            visited = jnp.ones((grid.nu, grid.p, q), bool)
+            if drop_cells is not None:
+                dc = jnp.asarray(drop_cells)[:, :, None]  # (nu, p, 1) over Q
+                kd = jnp.where(dc[..., None], jnp.inf, kd)
+                ki = jnp.where(dc[..., None], -1, ki)
+                comps = jnp.where(dc, 0, comps)
+                overflow = jnp.where(dc, 0, overflow)
+                visited = visited & ~dc
+            kd = jnp.moveaxis(kd, 2, 0).reshape(q, -1)
+            ki = jnp.moveaxis(ki, 2, 0).reshape(q, -1)
+            fd, fi = jax.vmap(
+                lambda a, b: topk.masked_topk_smallest(a, b, cfg.k)
+            )(kd, ki)
         return DistributedQueryResult(fd, fi, comps, overflow, visited)
 
-    pk = routing.probe_keys(routing.family_from_index(index), queries, cfg)
-    routed, scores = routing.route_mask(plan.occupancy, pk, grid)
-    if max_cells is not None:
-        routed = routing.apply_cell_budget(routed, scores, max_cells)
+    routed, scores = _route(index, plan.occupancy, queries, cfg, grid, max_cells)
     if drop_cells is not None:
         routed = routed & ~jnp.asarray(drop_cells)[None, :, :]
     mask = jnp.transpose(routed, (1, 2, 0))  # (nu, p, Q)
-    kd = jnp.where(mask[..., None], res.knn_dist, jnp.inf)
-    ki = jnp.where(mask[..., None], res.knn_idx, -1)
-    comps = jnp.where(mask, res.comparisons, 0)
-    overflow = jnp.where(mask, res.compaction_overflow, 0)
-    kd = jnp.where(drop_mask[:, None, None, None], jnp.inf, kd)
-    ki = jnp.where(drop_mask[:, None, None, None], -1, ki)
-    kd_s = kd.reshape(grid.cells, q, cfg.k)
-    ki_s = ki.reshape(grid.cells, q, cfg.k)
-    if plan.r_max > 1:
-        # stage 1: split each cell's partial across its replicas by row
-        # block, then reassemble — exercises the replica topology while
-        # staying exact (replicas own disjoint rows of identical indices)
-        owner = jnp.asarray(
-            np.stack(
-                [
-                    routing.replica_owner(q, int(plan.replicas[j, c]))
-                    for j in range(grid.nu)
-                    for c in range(grid.p)
-                ]
-            )
-        )  # (S, Q)
-        kd_r, ki_r = jax.vmap(
-            lambda a, b, o: routing.split_replicas(a, b, o, plan.r_max)
-        )(kd_s, ki_s, owner)
-        kd_s, ki_s = jax.vmap(
-            lambda a, b: routing.merge_replica_partials(a, b, cfg.k)
-        )(kd_r, ki_r)
-    fd, fi = routing.merge_partials_tree(kd_s, ki_s, cfg.k)
+    with jax.named_scope("dslsh.merge"):
+        kd = jnp.where(mask[..., None], res.knn_dist, jnp.inf)
+        ki = jnp.where(mask[..., None], res.knn_idx, -1)
+        comps = jnp.where(mask, res.comparisons, 0)
+        overflow = jnp.where(mask, res.compaction_overflow, 0)
+        kd = jnp.where(drop_mask[:, None, None, None], jnp.inf, kd)
+        ki = jnp.where(drop_mask[:, None, None, None], -1, ki)
+        kd_s = kd.reshape(grid.cells, q, cfg.k)
+        ki_s = ki.reshape(grid.cells, q, cfg.k)
+        if plan.r_max > 1:
+            # stage 1: split each cell's partial across its replicas by row
+            # block, then reassemble — exercises the replica topology while
+            # staying exact (replicas own disjoint rows of identical indices)
+            owner = jnp.asarray(
+                np.stack(
+                    [
+                        routing.replica_owner(q, int(plan.replicas[j, c]))
+                        for j in range(grid.nu)
+                        for c in range(grid.p)
+                    ]
+                )
+            )  # (S, Q)
+            kd_r, ki_r = jax.vmap(
+                lambda a, b, o: routing.split_replicas(a, b, o, plan.r_max)
+            )(kd_s, ki_s, owner)
+            kd_s, ki_s = jax.vmap(
+                lambda a, b: routing.merge_replica_partials(a, b, cfg.k)
+            )(kd_r, ki_r)
+        fd, fi = routing.merge_partials_tree(kd_s, ki_s, cfg.k)
     result = DistributedQueryResult(fd, fi, comps, overflow, mask)
     if not return_stats:
         return result

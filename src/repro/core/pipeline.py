@@ -873,7 +873,7 @@ def build_from_params(
     mode = _pick_build_mode(cfg, n)
     ob = obs_mod.get_active()
     if ob is not None and (traced or not ob.tracing):
-        ob = None  # sync-point policy: build spans only under eager tracing
+        ob = None  # synced build.* spans only for a traced eager build (§12.1)
     # Under a trace (grid and mesh cell programs) even the chunked mode
     # sorts each table whole after chunk-mapped hashing: the sorted-run
     # ladder would unroll into the program, one hash kernel and one merge
@@ -918,6 +918,25 @@ def build_from_params(
 # ------------------------------------------------------------ query stages
 
 
+def _stage(name: str):
+    """Trace the decorated stage function under ``jax.named_scope(
+    "dslsh.<name>")``: every operation it emits carries the stage in its
+    HLO ``op_name`` metadata, so a profiler trace attributes device time to
+    stages on any deployment (DESIGN.md §12.1). Metadata only: the program
+    and its instruction names stay as they are."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with jax.named_scope(f"dslsh.{name}"):
+                return fn(*args, **kwargs)
+
+        return run
+
+    return wrap
+
+
+@_stage("hash")
 def _stage_hash(
     index: SLSHIndex, queries: jax.Array, cfg: SLSHConfig, backend: BackendOps
 ) -> tuple[jax.Array, jax.Array]:
@@ -1062,6 +1081,7 @@ def _gather_one_table(
     return cand, bucket_sz
 
 
+@_stage("gather")
 def _stage_gather(
     index: SLSHIndex,
     cfg: SLSHConfig,
@@ -1113,6 +1133,7 @@ def _segmented_searchsorted(
     return lo
 
 
+@_stage("gather")
 def _gather_fast_parts(
     index: SLSHIndex,
     cfg: SLSHConfig,
@@ -1194,6 +1215,7 @@ def _gather_fast_parts(
     return outer_cand, inner_cand, found, bucket_sz
 
 
+@_stage("gather")
 def _gather_fast_select(
     cfg: SLSHConfig,
     outer_cand: jax.Array,  # (Q, L, slot)
@@ -1217,6 +1239,7 @@ def _gather_fast_select(
     )
 
 
+@_stage("gather")
 def _stage_gather_fast(
     index: SLSHIndex,
     cfg: SLSHConfig,
@@ -1245,6 +1268,7 @@ def _stage_gather_fast(
     return _gather_fast_select(cfg, outer_cand, inner_cand, found), bucket_sz
 
 
+@_stage("dedup")
 def _stage_dedup(cand: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 3 — static dedup: sort each row; first occurrence survives."""
     cand_sorted = jnp.sort(cand, axis=-1)
@@ -1269,6 +1293,7 @@ def _compact_width(cfg: SLSHConfig, c_total: int, n: int) -> int:
     return max(1, min(cc, -(-n // 128) * 128))
 
 
+@_stage("compact")
 def _stage_compact(
     cand_sorted: jax.Array,  # (Q, C)
     uniq: jax.Array,  # (Q, C)
@@ -1291,6 +1316,7 @@ def _stage_compact(
     return jnp.where(valid, comp, -1), valid, overflow
 
 
+@_stage("topk")
 def _stage_topk(
     data: jax.Array,
     queries: jax.Array,
@@ -1493,18 +1519,18 @@ def _fused_gather_delta_fn(cfg: SLSHConfig):
 
 
 def _traced_stage(ob, name: str, fn, *args):
-    """One traced stage dispatch: span + ``block_until_ready`` sync so
-    the span covers real device time, and the duration observed into the
-    per-stage latency histogram. Called only when tracing is enabled —
-    the sync point is the §12 sync-point policy, not the fast path."""
+    """One traced build phase: span + ``block_until_ready`` sync so the
+    span covers real device time, and the duration observed into the
+    per-stage latency histogram. Called only when tracing an eager build,
+    whose chunked schedule is the same traced or not (DESIGN.md §12.1)."""
     with ob.span(name) as sp:
         out = fn(*args)
         jax.block_until_ready(out)
     if ob.metrics is not None:
         ob.metrics.histogram(
             "dslsh_stage_latency_seconds",
-            "device time per eager query-pipeline stage dispatch"
-            " (recorded only under tracing — the sync-point policy)",
+            "device time per eager index-build phase (build.* spans;"
+            " recorded only under tracing)",
         ).labels(stage=name).observe(sp.dur_s)
     return out
 
@@ -1531,13 +1557,8 @@ def _query_batch_fused_eager(
     Inside an outer jit (tracers
     present) ``query_batch`` falls back to the traceable one-jit
     composition: bit-identical, just not dispatch-optimal (DESIGN.md §4).
-
-    When an ambient obs bundle has tracing enabled, every stage dispatch
-    is wrapped in a span with an explicit ``block_until_ready`` sync
-    point so per-stage durations are real device time, and each span's
-    duration feeds the ``dslsh_stage_latency_seconds`` histogram. The
-    sync points exist *only* under tracing — the steady-state fast path
-    checks one ContextVar and branches away (DESIGN.md §12).
+    Tracing changes nothing here: the stages' ``dslsh.*`` name scopes
+    attribute device time in a profiler trace (DESIGN.md §12.1).
     """
     q_n = queries.shape[0]
     chunk = min(cfg.query_chunk, q_n)
@@ -1564,34 +1585,16 @@ def _query_batch_fused_eager(
             )
         return backend.query_tail(d, q, c, run=run, c_comp=cc, k=cfg.k)
 
-    ob = obs_mod.get_active()
-    if ob is not None and not ob.tracing:
-        ob = None  # sync-point policy: per-stage timing only under tracing
     outs = []
     for i in range(n_chunks):
         qs = qp[i * chunk : (i + 1) * chunk]
-        if ob is None:
-            pk, ik = hash_fn(index, qs)
-            if delta is None:
-                oc, ic, fnd, bucket_total = parts_fn(index, pk, ik)
-                cand = select_fn(oc, ic, fnd)
-            else:
-                cand, bucket_total = gather_fn(index, pk, ik, delta)
-            out = tail(data, qs, cand)
+        pk, ik = hash_fn(index, qs)
+        if delta is None:
+            oc, ic, fnd, bucket_total = parts_fn(index, pk, ik)
+            cand = select_fn(oc, ic, fnd)
         else:
-            pk, ik = _traced_stage(ob, "query.hash", hash_fn, index, qs)
-            if delta is None:
-                oc, ic, fnd, bucket_total = _traced_stage(
-                    ob, "query.gather_work", parts_fn, index, pk, ik
-                )
-                cand = _traced_stage(
-                    ob, "query.gather_select", select_fn, oc, ic, fnd
-                )
-            else:
-                cand, bucket_total = _traced_stage(
-                    ob, "query.gather_delta", gather_fn, index, pk, ik, delta
-                )
-            out = _traced_stage(ob, "query.tail", tail, data, qs, cand)
+            cand, bucket_total = gather_fn(index, pk, ik, delta)
+        out = tail(data, qs, cand)
         if use_payload:
             kd, ki, comparisons, overflow, misses = out
         else:
@@ -1640,13 +1643,6 @@ def query_batch(
             index, data, queries, cfg, delta, backend, payload
         )
     fn = _staged_batch_fn(cfg, delta is not None)
-    ob = obs_mod.get_active()
-    if ob is not None and ob.tracing:
-        # the staged path is one whole-batch program — per-stage spans
-        # are a fused-path feature; record the one dispatch that exists
-        if delta is None:
-            return _traced_stage(ob, "query.batch", fn, index, data, queries)
-        return _traced_stage(ob, "query.batch", fn, index, data, queries, delta)
     if delta is None:
         return fn(index, data, queries)
     return fn(index, data, queries, delta)

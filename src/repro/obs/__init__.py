@@ -5,9 +5,11 @@ and a :class:`~repro.obs.metrics.MetricsRegistry` and threads through
 every layer: pass it to ``dslsh.build(..., obs=...)`` /
 ``dslsh.load(..., obs=...)``, :class:`~repro.serve.engine.ServeEngine`,
 or :class:`~repro.stream.monitor.StreamingMonitor`, or activate it
-ambiently with ``with obs.activate(): ...`` so nested calls (the eager
-per-stage query schedule, the kNN-LM hook's retrieval, streaming
-ingest) record into it without plumbing.
+ambiently with ``with obs.activate(): ...`` so nested calls (the serving
+front end's query, the kNN-LM hook's retrieval, streaming ingest) record
+into it without plumbing. Spans also land on a running
+``jax.profiler`` trace, where the query programs' ``dslsh.*`` name scopes
+attribute device time to pipeline stages (DESIGN.md §12.1).
 
 The disabled path is near-zero-cost by construction: an uninstrumented
 call site does one attribute check plus one ``ContextVar.get`` and
@@ -85,7 +87,7 @@ class Obs:
 
     @property
     def tracing(self) -> bool:
-        """True when spans record (controls the §12 sync-point policy)."""
+        """True when spans record."""
         return self.tracer is not None
 
     def span(self, name: str, **args):

@@ -9,14 +9,20 @@ tasks) each get their own depth chain (DESIGN.md §12).
 
 Timestamps come from :func:`repro.obs.clock.monotonic` relative to the
 tracer's creation, converted to the microseconds the trace-event format
-specifies. The disabled path is a single shared no-op span
-(:data:`NULL_SPAN`): entering it allocates nothing and reads no clock.
+specifies. Each recorded span is also a ``jax.profiler.TraceAnnotation``
+with the same name and args, so while a profiler trace runs the span
+lands on its host plane, on the clock of the device operations it
+dispatched (DESIGN.md §12.1). The disabled path is a single shared no-op
+span (:data:`NULL_SPAN`): entering it allocates nothing and reads no
+clock.
 """
 from __future__ import annotations
 
 import contextvars
 import json
 import threading
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import clock
 
@@ -25,10 +31,11 @@ class Span:
     """One in-flight timing span (a ``with tracer.span(...)`` body).
 
     ``dur_s`` is populated on exit; ``args`` are the key=value attributes
-    attached at open (they land in the trace event's ``args`` field).
+    attached at open or by :meth:`annotate` (they land in the trace
+    event's ``args`` field and in the profiler annotation's stats).
     """
 
-    __slots__ = ("tracer", "name", "args", "t0", "dur_s")
+    __slots__ = ("tracer", "name", "args", "t0", "dur_s", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -36,14 +43,25 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self.dur_s = 0.0
+        self._ann = None
 
     def __enter__(self) -> "Span":
         self.tracer._stack.set(self.tracer._stack.get() + 1)
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self.t0 = clock.monotonic()
         return self
 
+    def annotate(self, **args) -> None:
+        """Attach args known only after the span opened."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
     def __exit__(self, *exc) -> bool:
         t1 = clock.monotonic()
+        self._ann.__exit__(*exc)
+        self._ann = None
         self.dur_s = t1 - self.t0
         self.tracer._stack.set(self.tracer._stack.get() - 1)
         tr = self.tracer
@@ -69,6 +87,9 @@ class _NullSpan:
 
     def __enter__(self) -> "_NullSpan":
         return self
+
+    def annotate(self, **args) -> None:
+        pass
 
     def __exit__(self, *exc) -> bool:
         return False

@@ -34,6 +34,7 @@ import itertools
 import math
 from typing import Callable
 
+import jax
 import numpy as np
 
 from repro import obs as obs_mod
@@ -54,6 +55,10 @@ class ServeRequest:
     from admission); ``degraded`` is True iff the response was served
     under a §10 ``max_cells`` cap or with lost cells — an undegraded
     ``done`` response is bit-identical to a solo ``Index.query``.
+    ``started_at`` (the pump's time, on the front end's clock) and
+    ``batch`` (the micro-batch's sequence number, the ``batch`` arg of its
+    ``serve.pump`` span) are stamped when a micro-batch carries the
+    request, so ``started_at - submitted_at`` is its time in the queue.
     """
 
     rid: int
@@ -69,6 +74,8 @@ class ServeRequest:
     knn_dist: np.ndarray | None = None  # (nq, K)
     knn_idx: np.ndarray | None = None  # (nq, K)
     latency_s: float = 0.0  # submit → finalize (monotonic)
+    started_at: float | None = None  # its micro-batch's pump began
+    batch: int | None = None  # that micro-batch's sequence number
 
     @property
     def deadline_at(self) -> float:
@@ -184,6 +191,7 @@ class ServeFrontend:
         )
         self._queue: list[ServeRequest] = []
         self._rid = itertools.count()
+        self._batch = itertools.count()
         self._completed = 0
         self._timed_out = 0
         self._degraded_responses = 0
@@ -263,10 +271,11 @@ class ServeFrontend:
             rid=next(self._rid), tenant=tenant, queries=q,
             deadline_s=float(deadline_s), submitted_at=t,
         )
-        with self._activate():
+        with self._activate(), self._span("serve.submit", rid=req.rid) as sp:
             req.verdict = self.admission.admit(
                 tenant, req.n_queries, self.queue_depth, t
             )
+            sp.annotate(verdict=req.verdict)
         if req.verdict == admission_mod.Verdict.SHED:
             req.status = "shed"
             req.latency_s = 0.0
@@ -287,33 +296,46 @@ class ServeFrontend:
         the tightest slack in it (§15 scheduling), executes it on the
         current epoch, and scatters per-request result rows. Returns
         every request finalized this round (expired + served).
+
+        Traced, the round is a ``serve.pump`` span (args ``batch``,
+        ``rows``, ``bucket``, ``requests``, ``max_cells``) tiled by
+        ``serve.coalesce``, ``serve.dispatch``, ``serve.device_wait``,
+        ``serve.fetch`` and ``serve.scatter`` (DESIGN.md §12.1).
         """
         t = self._clock() if now is None else now
-        done = self._expire(t)
-        if not self._queue:
-            self._gauge_queue()
-            return done
-        self._queue.sort(key=lambda r: r.deadline_at)
-        mb = self.coalescer.form(self._queue)
-        self._gauge_queue()
-        cap = self._pick_cap(mb, t)
-        with self._activate(), self._span(
-            "serve.microbatch", rows=mb.n_real, bucket=mb.bucket,
-            requests=len(mb.requests),
-            max_cells=-1 if cap is None else cap,
-        ):
-            res, epoch_n, batch_lost = self._execute(mb, cap, t)
-            kd = np.asarray(res.knn_dist)  # syncs the device work
-            ki = np.asarray(res.knn_idx)
-        t_done = self._clock() if now is None else t
-        degraded = cap is not None or batch_lost
-        for req, (lo, hi) in zip(mb.requests, mb.spans):
-            req.knn_dist, req.knn_idx = kd[lo:hi], ki[lo:hi]
-            req.max_cells, req.epoch = cap, epoch_n
-            req.degraded = degraded
-            self._finalize(req, t_done, timed_out=t_done > req.deadline_at)
-            done.append(req)
-        self._record_batch(mb, cap, t_done - t)
+        with self._activate(), self._span("serve.pump") as pump:
+            with self._span("serve.coalesce"):
+                done = self._expire(t)
+                if not self._queue:
+                    self._gauge_queue()
+                    return done
+                self._queue.sort(key=lambda r: r.deadline_at)
+                mb = self.coalescer.form(self._queue)
+                self._gauge_queue()
+                cap = self._pick_cap(mb, t)
+            seq = next(self._batch)
+            pump.annotate(
+                batch=seq, rows=mb.n_real, bucket=mb.bucket,
+                requests=len(mb.requests), max_cells=-1 if cap is None else cap,
+            )
+            with self._span("serve.dispatch"):
+                res, epoch_n, batch_lost = self._execute(mb, cap, t)
+            with self._span("serve.device_wait"):
+                jax.block_until_ready(res)
+            with self._span("serve.fetch"):
+                kd = np.asarray(res.knn_dist)
+                ki = np.asarray(res.knn_idx)
+            t_done = self._clock() if now is None else t
+            with self._span("serve.scatter"):
+                degraded = cap is not None or batch_lost
+                for req, (lo, hi) in zip(mb.requests, mb.spans):
+                    req.knn_dist, req.knn_idx = kd[lo:hi], ki[lo:hi]
+                    req.max_cells, req.epoch = cap, epoch_n
+                    req.started_at, req.batch = t, seq
+                    req.degraded = degraded
+                    self._finalize(req, t_done, timed_out=t_done > req.deadline_at)
+                    done.append(req)
+                self._record_batch(mb, cap, t_done - t)
         return done
 
     def drain(self, now: float | None = None) -> list[ServeRequest]:

@@ -7,7 +7,9 @@ lands in exactly one micro-batch, padding never exceeds the gap to the
 chosen rung, and per-request result rows are bit-identical to a solo
 ``Index.query`` when no degradation fired.
 """
+import contextlib
 import math
+import time
 
 import jax
 import numpy as np
@@ -179,6 +181,94 @@ def test_steady_state_serving_retraces_nothing(grid_index):
     fe.drain(now=t)
     assert obs_mod.query_retraces() == r0, "steady state must not retrace"
     fe.assert_conserved()
+
+
+PUMP_CHILDREN = ("serve.coalesce", "serve.dispatch", "serve.device_wait",
+                 "serve.fetch", "serve.scatter")
+
+
+def _serve_rounds(idx, data, ob=None, hold_s=0.0):
+    """Serve the same seeded arrivals pump by pump (optionally under an
+    active ``ob``, each query held ``hold_s`` longer) -> (tickets, query
+    retraces after warmup)."""
+    rng = np.random.default_rng(11)
+    handle = idx.with_obs(None)
+    fe = handle.frontend(frontend_mod.FrontendConfig(ladder=(8, 32)))
+    fe.warmup()
+    if hold_s:
+        query = handle.query
+
+        def held(q, **kw):
+            time.sleep(hold_s)
+            return query(q, **kw)
+
+        handle.query = held
+    r0 = obs_mod.query_retraces()
+    tickets = []
+    with ob.activate() if ob is not None else contextlib.nullcontext():
+        for _ in range(4):
+            for _ in range(int(rng.integers(1, 5))):
+                nq = int(rng.integers(1, 9))
+                tickets.append(fe.submit(
+                    data[rng.integers(0, len(data), nq)].astype(np.float32)))
+            fe.pump()
+    fe.assert_conserved()
+    return tickets, obs_mod.query_retraces() - r0
+
+
+def test_traced_pump_spans_tile_it(grid_index):
+    """Under an active Obs each pump() records one ``serve.pump`` span with
+    the micro-batch's args, tiled (within 1%) by its five children in
+    order; every submit records ``serve.submit`` with its rid and
+    verdict. Each query is held 100 ms, a pump as long as one on the chip:
+    the spans' fixed cost on a CPU host, up to a few hundred µs a pump,
+    would otherwise pass 1% of a CPU pump of a few ms."""
+    idx, data = grid_index
+    ob = obs_mod.Obs(metrics=False)
+    tickets, _ = _serve_rounds(idx, data, ob, hold_s=0.1)
+    ev = ob.tracer.events
+    pumps = sorted((e for e in ev if e["name"] == "serve.pump"), key=lambda e: e["ts"])
+    assert [p["args"]["batch"] for p in pumps] == [0, 1, 2, 3]
+    for p in pumps:
+        lo, hi = p["ts"], p["ts"] + p["dur"]
+        inside = sorted((e for e in ev if e is not p and lo <= e["ts"] <= hi),
+                        key=lambda e: e["ts"])
+        kids = [e for e in inside if e["name"] in PUMP_CHILDREN]
+        assert tuple(e["name"] for e in kids) == PUMP_CHILDREN
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+        assert kids[-1]["ts"] + kids[-1]["dur"] <= hi
+        assert sum(e["dur"] for e in kids) >= 0.99 * p["dur"]
+        served = [t for t in tickets if t.batch == p["args"]["batch"]]
+        assert p["args"]["requests"] == len(served)
+        assert p["args"]["rows"] == sum(t.n_queries for t in served)
+        assert p["args"]["bucket"] in (8, 32) and p["args"]["max_cells"] == -1
+        query = [e for e in inside if e["name"] == "index.query"]
+        assert len(query) == 1  # inside serve.dispatch
+        assert kids[1]["ts"] <= query[0]["ts"]
+        assert query[0]["ts"] + query[0]["dur"] <= kids[1]["ts"] + kids[1]["dur"]
+    submits = [e["args"] for e in ev if e["name"] == "serve.submit"]
+    assert [a["rid"] for a in submits] == [t.rid for t in tickets]
+    assert {a["verdict"] for a in submits} == {admission.Verdict.ADMIT}
+
+
+def test_served_requests_carry_queue_stamps(grid_index):
+    """Every served request carries ``started_at`` and ``batch``, with
+    ``submitted_at <= started_at <= submitted_at + latency_s``; tracing
+    changes neither the answers (bit-identical) nor the compiled programs
+    (no retrace)."""
+    idx, data = grid_index
+    bare, bare_retraces = _serve_rounds(idx, data)
+    traced, traced_retraces = _serve_rounds(idx, data, obs_mod.Obs())
+    assert bare_retraces == traced_retraces == 0
+    assert len(bare) == len(traced)
+    for a, b in zip(bare, traced):
+        for t in (a, b):
+            assert t.status == "done" and t.batch is not None
+            assert t.submitted_at <= t.started_at <= t.submitted_at + t.latency_s
+        assert a.batch == b.batch
+        np.testing.assert_array_equal(a.knn_dist, b.knn_dist)
+        np.testing.assert_array_equal(a.knn_idx, b.knn_idx)
 
 
 def test_deadline_degradation_and_expiry(grid_index):

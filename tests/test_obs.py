@@ -309,37 +309,43 @@ def test_disabled_obs_has_no_recording_surface():
 # ------------------------------------------------------------ end-to-end
 
 
-def test_instrumented_query_yields_trace_and_metrics(tmp_path):
+def test_instrumented_query_yields_trace_and_metrics(tmp_path, monkeypatch):
     """The acceptance scenario: one instrumented single-deployment query
-    produces (a) a Perfetto-loadable trace with per-stage spans and (b) a
-    snapshot with latency histograms + the paper's accounting signals —
-    bit-identical to the uninstrumented result."""
+    records an ``index.query`` span and a snapshot with latency histograms
+    + the paper's accounting signals, while running the very jitted
+    program the bare handle runs (no eager per-stage schedule, no new
+    trace) — bit-identical to the uninstrumented result."""
+    from repro.core import pipeline
+
     cfg = _cfg()
     data = jax.random.uniform(jax.random.PRNGKey(0), (256, 16))
     q = jax.random.uniform(jax.random.PRNGKey(1), (32, 16))
     ob = obs.Obs()
     idx = dslsh.build(jax.random.PRNGKey(2), data, cfg, dslsh.single(), obs=ob)
-    res = idx.query(q)
     bare = idx.with_obs(None)
-    np.testing.assert_array_equal(
-        np.asarray(res.knn_idx), np.asarray(bare.query(q).knn_idx)
-    )
+    want = bare.query(q)
+    compiled = (obs.retraces("single_query"), obs.query_retraces())
+
+    def eager(*a, **k):
+        raise AssertionError("tracing ran the eager per-stage schedule")
+
+    monkeypatch.setattr(pipeline, "_query_batch_fused_eager", eager)
+    res = idx.query(q)
+    assert (obs.retraces("single_query"), obs.query_retraces()) == compiled
+    for got, ref in zip(jax.tree.leaves(res), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     names = {e["name"] for e in ob.tracer.events}
-    assert {"index.build", "index.query", "query.hash", "query.gather_work",
-            "query.gather_select", "query.tail"} <= names
-    # index.query wraps the stage spans (time containment on one track)
+    assert {"index.build", "index.query"} <= names
+    assert not any(n.startswith("query.") for n in names)
     top = next(e for e in ob.tracer.events if e["name"] == "index.query")
     assert top["args"]["deployment"] == "single" and top["args"]["queries"] == 32
-    for e in ob.tracer.events:
-        if e["name"].startswith("query."):
-            assert e["ts"] >= top["ts"]
-            assert e["ts"] + e["dur"] <= top["ts"] + top["dur"] + 1.0
     snap = ob.snapshot()
     assert snap["dslsh_queries_total"]["values"]['deployment="single"'] == 1.0
     lat = snap["dslsh_query_latency_seconds"]["values"]['deployment="single"']
     assert lat["count"] == 1 and lat["sum"] > 0.0
+    # only the eager build's phases feed the stage histogram
     stages = snap["dslsh_stage_latency_seconds"]["values"]
-    assert {'stage="query.hash"', 'stage="query.tail"'} <= set(stages)
+    assert stages and all(k.startswith('stage="build.') for k in stages)
     assert snap["dslsh_comparisons_total"]["values"][""] > 0
     assert snap["dslsh_compaction_overflow_total"]["values"][""] >= 0
     assert snap["dslsh_jit_retraces_total"]["values"]['stage="query_tail"'] >= 1
@@ -350,6 +356,39 @@ def test_instrumented_query_yields_trace_and_metrics(tmp_path):
     m_path = ob.save_metrics(str(tmp_path / "metrics.json"))
     assert "dslsh_queries_total" in json.loads(open(m_path).read())
     assert "# TYPE dslsh_query_latency_seconds histogram" in ob.prometheus()
+
+
+STAGE_SCOPES = ("dslsh.hash", "dslsh.gather", "dslsh.dedup", "dslsh.compact",
+                "dslsh.topk")
+
+
+def test_query_programs_carry_stage_scopes():
+    """The compiled query program of a routed grid and of a single
+    deployment holds each ``dslsh.<stage>`` scope in its ``op_name``
+    metadata (the grid's also ``dslsh.route`` and ``dslsh.merge``), and
+    lowering it under an active Obs gives the same program."""
+    cfg = _cfg(backend="reference")  # the staged stages 3-5 the chip runs
+    data = jax.random.uniform(jax.random.PRNGKey(0), (256, 16))
+    q = jax.random.uniform(jax.random.PRNGKey(1), (8, 16))
+    dm, dc = np.zeros((2,), bool), np.zeros((2, 1), bool)
+    grid = dslsh.build(jax.random.PRNGKey(2), data, cfg,
+                       dslsh.grid(nu=2, p=1, routed=True))
+    single = dslsh.build(jax.random.PRNGKey(2), data, cfg, dslsh.single())
+    g, s = grid._grid_fn(None), single._single_fn()
+    lowered = {
+        "grid": g.func.lower(*g.args, q, dm, dc),
+        "single": s.func.lower(*s.args, q),
+    }
+    want = {"grid": STAGE_SCOPES + ("dslsh.route", "dslsh.merge"),
+            "single": STAGE_SCOPES}
+    for name, low in lowered.items():
+        scopes = set(re.findall(r'op_name="[^"]*?(dslsh\.\w+)',
+                                low.compile().as_text()))
+        assert scopes == set(want[name]), name
+    with obs.Obs().activate():
+        traced = g.func.lower(*g.args, q, dm, dc)
+    assert (traced.as_text(debug_info=True)
+            == lowered["grid"].as_text(debug_info=True))
 
 
 def test_instrumented_chunked_build_spans_and_index_bytes():
