@@ -57,6 +57,7 @@ MODULES: tuple[str, ...] = (
     "repro.launch.mesh",
     "repro.kernels.blocking",
     "repro.kernels.hash_pack.ops",
+    "repro.kernels.inner_probe.ops",
     "repro.kernels.l1_topk.ops",
     "repro.kernels.query_fused.ops",
     "repro.kernels.flash_attention.ops",

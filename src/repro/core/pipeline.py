@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from repro import obs as obs_mod
 from repro.core import hashing, merge, tables, topk
-from repro.obs.metrics import count_retrace
+from repro.obs.metrics import count_inner_probe, count_retrace
 from repro.runtime.payload import Payload, make_payload
 
 # ------------------------------------------------------------ configuration
@@ -498,6 +498,14 @@ class BackendOps(NamedTuple):
         an exact f32 rerank of the ``c_rerank`` shortlist (DESIGN.md §13).
         Used only when ``cfg.payload != "f32"``; ``None`` falls back to
         the exact ``query_tail`` (correct, just uncompressed).
+    inner_probe (optional, default ``None``)
+        ``(inner_keys, inner_idx, h (L, Q), keys (Q, L_in), c_in=) ->
+        (L, Q, L_in * c_in)`` — the stratified layer's probe of each
+        (table, query) pair's heavy bucket ``h``, read as whole slabs
+        (``kernels/inner_probe``, DESIGN.md §3). ``None`` keeps the
+        staged gather's element takes; delta queries always keep them.
+        Must equal the takes bit for bit: ``c_in`` indices of the key's
+        run per inner table, -1 past it.
     """
 
     signature_words: Callable[..., jax.Array]
@@ -505,6 +513,7 @@ class BackendOps(NamedTuple):
     probe_words: Callable[..., tuple[jax.Array, jax.Array]] | None = None
     query_tail: Callable[..., tuple[jax.Array, ...]] | None = None
     query_tail_payload: Callable[..., tuple[jax.Array, ...]] | None = None
+    inner_probe: Callable[..., jax.Array] | None = None
 
 
 _BACKENDS: dict[str, BackendOps | Callable[["SLSHConfig | None"], BackendOps]] = {}
@@ -577,15 +586,27 @@ def _pallas_query_tail_payload(
     )
 
 
+def _pallas_inner_probe(
+    inner_keys, inner_idx, h, keys, *, c_in, interpret: bool | None = None
+):
+    from repro.kernels.inner_probe import ops as ip_ops
+
+    return ip_ops.inner_probe(
+        inner_keys, inner_idx, h, keys, c_in=c_in, interpret=interpret
+    )
+
+
 def _pallas_ops(cfg: "SLSHConfig | None") -> BackendOps:
     """The pallas backend: Mosaic kernels, compiled on TPU and interpreted
     elsewhere (``blocking.resolve_interpret``; ``cfg.interpret`` forces
     either). Compiled, Mosaic refuses the fused f32 tail's body
     (``kernels.query_fused.query_fused.XLA_STAGES``), so the backend has no
     ``query_tail`` there: the f32 tail runs the staged stages 3-5 as XLA
-    ops, stage 5 in the ``l1_topk`` kernel. The compressed-payload tail
-    keeps its dedup, compaction, gathers and selections as XLA ops around
-    the ``l1_topk`` kernel's distance mode."""
+    ops, stage 5 in the ``l1_topk`` kernel, and the staged gather probes
+    the inner layer with the ``inner_probe`` kernel. Interpreted, the fused
+    path's flat gather takes its place (element takes are cheap there).
+    The compressed-payload tail keeps its dedup, compaction, gathers and
+    selections as XLA ops around the ``l1_topk`` kernel's distance mode."""
     from repro.kernels import blocking
 
     interp = None if cfg is None else cfg.interpret
@@ -600,6 +621,10 @@ def _pallas_ops(cfg: "SLSHConfig | None") -> BackendOps:
         ),
         query_tail_payload=functools.partial(
             _pallas_query_tail_payload, interpret=interp
+        ),
+        inner_probe=(
+            functools.partial(_pallas_inner_probe, interpret=interp)
+            if compiled else None
         ),
     )
 
@@ -993,6 +1018,22 @@ def _merge_capped(base_cand: jax.Array, delta_match: jax.Array, delta_gidx: jax.
     return jnp.where(merged == _IDX_SENTINEL, -1, merged)
 
 
+def _heavy_match(
+    index: SLSHIndex, base_keys: jax.Array  # (Q, L) base probe key per table
+) -> tuple[jax.Array, jax.Array]:
+    """Match each (query, table) base key against the heavy-bucket registry.
+
+    Returns ``found (Q, L)`` and the registry slot ``h (Q, L)``: the first
+    valid slot holding the key (argmax's tie rule), 0 where none does.
+    (Streaming note: the registry is the *base* one — stratification is
+    frozen between compactions, DESIGN.md §9.)
+    """
+    match = (
+        index.heavy.keys[None, :, :] == base_keys[:, :, None]
+    ) & index.heavy.valid[None, :, :]  # (Q, L, H)
+    return jnp.any(match, axis=-1), jnp.argmax(match, axis=-1).astype(jnp.int32)
+
+
 def _gather_one_table(
     index: SLSHIndex,
     cfg: SLSHConfig,
@@ -1000,6 +1041,7 @@ def _gather_one_table(
     probe_keys_t: jax.Array,  # (Q, 1 + multiprobe) base key first
     inner_keys: jax.Array,  # (Q, L_in)
     delta: DeltaView | None = None,
+    heavy: tuple[jax.Array, jax.Array, jax.Array | None] | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """All queries' candidates (Q, slot) for one outer table; -1 where masked.
 
@@ -1009,7 +1051,10 @@ def _gather_one_table(
     lowered to a swarm of tiny binary-search gathers. When ``delta`` is
     given, each probe fans out over base + delta segments and the merged
     candidate set equals the one a from-scratch build over the union would
-    gather (DESIGN.md §9).
+    gather (DESIGN.md §9). ``heavy`` (``use_inner`` only) is this table's
+    registry match ``(found (Q,), h (Q,), inner)``; ``inner`` is the inner
+    layer already probed by the backend's ``inner_probe`` ``(Q, L_in *
+    c_in)``, or None for the element takes below (DESIGN.md §3).
     """
     sk_row = index.outer.sorted_keys[l]
     si_row = index.outer.sorted_idx[l]
@@ -1029,30 +1074,7 @@ def _gather_one_table(
 
     slot = cfg.slot
 
-    def per_query(lo_q, hi_q, keys_q, in_keys_q, d_outer_q):
-        def probe(lo1, hi1, key1):
-            cand = tables.gather_bucket(si_row, lo1, hi1, cfg.c_max)
-            if delta is None:
-                return cand
-            dm = delta.valid & (delta.outer_keys[:, l] == key1)
-            return _merge_capped(cand, dm, delta.gidx, cfg.c_max)
-
-        outer_cand = jax.vmap(probe)(lo_q, hi_q, keys_q).reshape(-1)
-        outer_cand = jnp.pad(
-            outer_cand, (0, slot - outer_cand.shape[0]), constant_values=-1
-        )
-
-        if not cfg.use_inner:
-            return outer_cand
-
-        # Is this bucket stratified? Match against the heavy-bucket registry.
-        # (Streaming note: the registry is the *base* one — stratification is
-        # frozen between compactions, DESIGN.md §9.)
-        q_key = keys_q[0]
-        match = (index.heavy.keys[l] == q_key) & index.heavy.valid[l]
-        found = jnp.any(match)
-        h = jnp.argmax(match)
-
+    def element_probe(h, in_keys_q, d_outer_q):
         if delta is not None:
             # Delta members of this heavy bucket join its inner-layer
             # population in global-index order until the P_max cap —
@@ -1071,13 +1093,34 @@ def _gather_one_table(
             dm = d_in_pop & (delta.inner_keys[:, li] == in_keys_q[li])
             return _merge_capped(cand, dm, delta.gidx, cfg.c_in)
 
-        inner_cand = jax.vmap(inner_one)(jnp.arange(cfg.L_in)).reshape(-1)
+        return jax.vmap(inner_one)(jnp.arange(cfg.L_in)).reshape(-1)
+
+    def per_query(lo_q, hi_q, keys_q, in_keys_q, d_outer_q, heavy_q):
+        def probe(lo1, hi1, key1):
+            cand = tables.gather_bucket(si_row, lo1, hi1, cfg.c_max)
+            if delta is None:
+                return cand
+            dm = delta.valid & (delta.outer_keys[:, l] == key1)
+            return _merge_capped(cand, dm, delta.gidx, cfg.c_max)
+
+        outer_cand = jax.vmap(probe)(lo_q, hi_q, keys_q).reshape(-1)
+        outer_cand = jnp.pad(
+            outer_cand, (0, slot - outer_cand.shape[0]), constant_values=-1
+        )
+
+        if heavy_q is None:
+            return outer_cand
+
+        # Stratified bucket: its inner-layer candidates replace the outer.
+        found, h, inner_cand = heavy_q
+        if inner_cand is None:
+            inner_cand = element_probe(h, in_keys_q, d_outer_q)
         inner_cand = jnp.pad(
             inner_cand, (0, slot - cfg.L_in * cfg.c_in), constant_values=-1
         )
         return jnp.where(found, inner_cand, outer_cand)
 
-    cand = jax.vmap(per_query)(lo, hi, probe_keys_t, inner_keys, d_outer)
+    cand = jax.vmap(per_query)(lo, hi, probe_keys_t, inner_keys, d_outer, heavy)
     return cand, bucket_sz
 
 
@@ -1088,6 +1131,7 @@ def _stage_gather(
     probe_keys: jax.Array,  # (Q, L, 1 + multiprobe)
     inner_keys: jax.Array,  # (Q, L_in)
     delta: DeltaView | None = None,
+    inner_probe: Callable[..., jax.Array] | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Stage 2 — dense candidate tensor (Q, L*slot) + probed bucket sizes.
 
@@ -1095,13 +1139,29 @@ def _stage_gather(
     one batched binary search (``_gather_one_table``); the per-(query,
     table) candidate blocks then transpose back to query-major rows. Row
     order differs from the old query-major gather only *within* a row —
-    irrelevant after the dedup sort.
+    irrelevant after the dedup sort. The heavy-registry match runs once
+    for the chunk (``_heavy_match``); with the backend's ``inner_probe``
+    and no delta, the kernel reads each pair's heavy bucket as one slab
+    (DESIGN.md §3). The path taken is counted as the program traces
+    (``count_inner_probe``).
     """
     l_out = index.outer.sorted_keys.shape[0]
     pk_lt = jnp.moveaxis(probe_keys, 1, 0)  # (L, Q, 1 + multiprobe)
+    heavy = None
+    if cfg.use_inner:
+        found, h = _heavy_match(index, probe_keys[:, :, 0])  # (Q, L)
+        inner_cand = None
+        if inner_probe is not None and delta is None:
+            inner_cand = inner_probe(
+                index.inner_keys, index.inner_idx, h.T, inner_keys, c_in=cfg.c_in
+            )  # (L, Q, L_in * c_in)
+        count_inner_probe("element" if inner_cand is None else "slab")
+        heavy = (found.T, h.T, inner_cand)
     cand, bucket_sz = jax.vmap(
-        lambda l, pk: _gather_one_table(index, cfg, l, pk, inner_keys, delta)
-    )(jnp.arange(l_out), pk_lt)  # (L, Q, slot), (L, Q)
+        lambda l, pk, hv: _gather_one_table(
+            index, cfg, l, pk, inner_keys, delta, hv
+        )
+    )(jnp.arange(l_out), pk_lt, heavy)  # (L, Q, slot), (L, Q)
     cand = jnp.moveaxis(cand, 0, 1).reshape(probe_keys.shape[0], -1)
     return cand, jnp.sum(bucket_sz, axis=0)
 
@@ -1181,13 +1241,9 @@ def _gather_fast_parts(
     if not cfg.use_inner:
         return outer_cand, None, None, bucket_sz
 
+    count_inner_probe("element")
     h_max = index.heavy.keys.shape[1]
-    base_keys = jnp.moveaxis(pk, 0, 1)[:, :, 0]  # (Q, L)
-    match = (
-        index.heavy.keys[None, :, :] == base_keys[:, :, None]
-    ) & index.heavy.valid[None, :, :]  # (Q, L, H)
-    found = jnp.any(match, axis=-1)  # (Q, L)
-    h = jnp.argmax(match, axis=-1).astype(jnp.int32)
+    found, h = _heavy_match(index, probe_keys[:, :, 0])  # (Q, L)
 
     p_in = index.inner_keys.shape[-1]
     ik_pool = index.inner_keys.reshape(-1)
@@ -1419,7 +1475,9 @@ def query_chunk(
         )
         return QueryResult(ki, kd, comparisons, bucket_total, overflow)
     probe_keys, inner_keys = _stage_hash(index, queries, cfg, backend)
-    cand, bucket_total = _stage_gather(index, cfg, probe_keys, inner_keys, delta)
+    cand, bucket_total = _stage_gather(
+        index, cfg, probe_keys, inner_keys, delta, backend.inner_probe
+    )
     cand_sorted, uniq, comparisons = _stage_dedup(cand)
     cc = _compact_width(cfg, cand.shape[1], data.shape[0])
     comp_cand, comp_valid, overflow = _stage_compact(

@@ -37,7 +37,9 @@ from repro.obs.metrics import (  # noqa: F401  (re-export)
     GLOBAL,
     LATENCY_BUCKETS,
     MetricsRegistry,
+    count_inner_probe,
     count_retrace,
+    inner_probe_count,
     log_buckets,
     retrace_count,
 )

@@ -15,8 +15,10 @@ spans decades, so linear buckets waste resolution where it matters.
 
 :data:`GLOBAL` is the process-global registry for signals that are
 process facts rather than per-handle facts — jit retrace counts
-(:func:`count_retrace` / :func:`retrace_count`), fed from inside traced
-function bodies, which run once per compile-cache miss.
+(:func:`count_retrace` / :func:`retrace_count`) and the inner-layer probe
+path each query program compiled (:func:`count_inner_probe` /
+:func:`inner_probe_count`), fed from inside traced function bodies, which
+run once per compile-cache miss.
 """
 from __future__ import annotations
 
@@ -324,3 +326,22 @@ def retrace_count(stage: str) -> int:
     """Total (re)traces recorded for ``stage`` in this process — the
     public counter ``tests/test_compile_cache.py`` pins."""
     return int(_RETRACES.labels(stage=stage).value)
+
+
+_INNER_PROBES = GLOBAL.counter(
+    "dslsh_inner_probe_traces_total",
+    "query programs traced per inner-layer probe path: slab (the backend's"
+    " inner_probe kernel) or element (takes) (DESIGN.md §3/§12)",
+)
+
+
+def count_inner_probe(path: str) -> None:
+    """Record that a query program traced the inner-layer probe ``path``
+    (``"slab"`` or ``"element"``). Like :func:`count_retrace` it runs
+    only while tracing, so steady-state dispatch never touches it."""
+    _INNER_PROBES.labels(path=path).inc()
+
+
+def inner_probe_count(path: str) -> int:
+    """Total traces recorded for inner-probe ``path`` in this process."""
+    return int(_INNER_PROBES.labels(path=path).value)

@@ -391,6 +391,56 @@ def test_query_programs_carry_stage_scopes():
             == lowered["grid"].as_text(debug_info=True))
 
 
+def test_inner_probe_path_counter():
+    """Each query program counts, as it traces, the inner-layer probe it
+    holds: ``slab`` where the backend has ``inner_probe`` and no delta is
+    given (the compiled pallas backend; on the CPU, the reference backend
+    with the kernel interpreted), ``element`` for the reference backend,
+    the interpreted pallas backend and a delta query."""
+    import functools
+
+    from repro import stream
+    from repro.core import pipeline
+    from repro.kernels.inner_probe import ops as ip_ops
+
+    data = jax.random.uniform(jax.random.PRNGKey(0), (256, 16))
+    q = data[:8]
+    cfg = _cfg(backend="reference")
+    idx = slsh.build_index(jax.random.PRNGKey(1), data, cfg)
+    sidx = stream.insert_batch(
+        stream.stream_init(jax.random.PRNGKey(1), data[:200], cfg,
+                           capacity=256, delta_cap=56),
+        data[200:], cfg,
+    )
+
+    def paths(fn, run=True):
+        """Path counts one fresh trace of ``fn(q)`` adds."""
+        before = {p: obs.inner_probe_count(p) for p in ("slab", "element")}
+        (jax.jit(fn) if run else functools.partial(jax.eval_shape, fn))(q)
+        return {p: obs.inner_probe_count(p) - n for p, n in before.items()}
+
+    # compiled pallas: traced only, its Mosaic kernels need the chip
+    compiled = cfg.replace(backend="pallas", interpret=False)
+    assert pipeline.get_backend("pallas", compiled).inner_probe is not None
+    assert paths(lambda x: slsh.query_batch(idx, data, x, compiled),
+                 run=False) == {"slab": 1, "element": 0}
+    probe = functools.partial(ip_ops.inner_probe, interpret=True)
+    pipeline.register_backend(
+        "_slab_obs", pipeline.get_backend("reference")._replace(inner_probe=probe)
+    )
+    try:
+        slab = cfg.replace(backend="_slab_obs")
+        assert paths(lambda x: slsh.query_batch(idx, data, x, slab)) == {
+            "slab": 1, "element": 0}
+        assert paths(lambda x: stream.query_batch(sidx, x, slab)) == {
+            "slab": 0, "element": 1}
+    finally:
+        pipeline._BACKENDS.pop("_slab_obs", None)
+    for other in (cfg, cfg.replace(backend="pallas")):
+        assert paths(lambda x: slsh.query_batch(idx, data, x, other)) == {
+            "slab": 0, "element": 1}
+
+
 def test_instrumented_chunked_build_spans_and_index_bytes():
     """An instrumented out-of-core build records the §13 build-stage spans
     (hash -> sort_runs -> merge -> heavy_inner) inside index.build, and

@@ -7,13 +7,17 @@ including multiprobe and ``use_inner=False`` configs. Also pins the shared
 builder: ``cell_build`` on a 1x1 grid must equal ``build_index`` exactly.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs, stream
 from repro.core import distributed as D
 from repro.core import pipeline, slsh
+from repro.kernels.inner_probe import ops as ip_ops
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -197,3 +201,48 @@ def test_backend_registry_contract():
         assert calls["words"] > 0 and calls["topk"] > 0
     finally:
         pipeline._BACKENDS.pop("_test", None)
+
+
+@pytest.fixture
+def slab_backend():
+    """The reference backend plus the inner-probe kernel (interpreted):
+    the staged pipeline with the slab probe the compiled pallas backend
+    takes on a TPU."""
+    ref = pipeline.get_backend("reference")
+    probe = functools.partial(ip_ops.inner_probe, interpret=True)
+    pipeline.register_backend("_slab", ref._replace(inner_probe=probe))
+    yield "_slab"
+    pipeline._BACKENDS.pop("_slab", None)
+
+
+@pytest.mark.parametrize("deploy", ["grid", "stream_delta"])
+def test_staged_query_with_inner_probe_identical(slab_backend, deploy):
+    """A staged query with the slab probe injected and without it gives
+    identical results: on a simulated grid (the probe runs in every
+    cell), and on a streaming index, whose delta queries keep the element
+    takes while its base alone takes the probe."""
+    data = _data()
+    cfg = _cfg()
+    cfg_s = cfg.replace(backend=slab_backend)
+    q = data[:24] + 0.01 * jax.random.normal(jax.random.PRNGKey(2), (24, 12))
+    slabs = obs.inner_probe_count("slab")
+    if deploy == "grid":
+        grid = D.Grid(nu=2, p=1)
+        idx = D.simulate_build(jax.random.PRNGKey(0), data, cfg, grid)
+        _assert_trees_equal(
+            D.grid_query(idx, data, q, cfg_s, grid),
+            D.grid_query(idx, data, q, cfg, grid),
+        )
+    else:
+        sidx = stream.stream_init(
+            jax.random.PRNGKey(1), data[:400], cfg, capacity=512, delta_cap=112
+        )
+        sidx = stream.insert_batch(sidx, data[400:], cfg, t=1.0)
+        _assert_trees_equal(
+            stream.query_batch(sidx, q, cfg_s), stream.query_batch(sidx, q, cfg)
+        )
+        _assert_trees_equal(
+            pipeline.query_batch(sidx.base, sidx.store, q, cfg_s),
+            pipeline.query_batch(sidx.base, sidx.store, q, cfg),
+        )
+    assert obs.inner_probe_count("slab") > slabs

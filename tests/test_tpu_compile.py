@@ -3,9 +3,11 @@
 Every other test runs the kernels in interpret mode, which accepts shapes
 and ops the TPU compiler refuses. These tests lower the compiled (Mosaic)
 formulation at the paper deployment's widths (d=30, m_out=32, L_out=16,
-k=10, c_comp=256, Q=64) for one chip of a ``v5e:2x2`` topology described
-without a chip, check that a Mosaic kernel (``tpu_custom_call``) is in the
-executable, and record its ``memory_analysis()``.
+k=10, c_comp=256, Q=64) — the inner probe at the benchmark cell's (L_out=63,
+h_max=8, L_in=20, p_max=512, c_in=32) — for one chip of a ``v5e:2x2``
+topology described without a chip, check that a Mosaic kernel
+(``tpu_custom_call``) is in the executable, and record its
+``memory_analysis()``.
 
 The topology is described only inside the module fixture: describing it
 loads the TPU compiler library, which one process at a time may hold.
@@ -17,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import hashing, pipeline, slsh
 from repro.kernels.hash_pack import ops as hp_ops
+from repro.kernels.inner_probe import ops as ip_ops
 from repro.kernels.l1_topk import ops as l1_ops
 from repro.kernels.query_fused import ops as qf_ops
 
@@ -148,4 +151,25 @@ def test_query_fused_payload_compile(one_chip, record_property, dtype):
         jax.ShapeDtypeStruct((N, 2), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((Q, D), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((Q, C), jnp.int32, sharding=one_chip),
+    )
+
+
+@pytest.mark.parametrize("q", [8, 64])
+def test_inner_probe_compile(one_chip, record_property, q):
+    """The slab probe at the benchmark cell's widths, for both rungs'
+    chunk sizes (8 rows, and the 64-row query chunk)."""
+    l_out, h_max, l_in, p_max, c_in = 63, 8, 20, 512, 32
+
+    def fn(inner_keys, inner_idx, h, keys):
+        return ip_ops.inner_probe(
+            inner_keys, inner_idx, h, keys, c_in=c_in, interpret=False
+        )
+
+    slab = (l_out, h_max, l_in, p_max)
+    _compile(
+        record_property, fn,
+        jax.ShapeDtypeStruct(slab, jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct(slab, jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((l_out, q), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((q, l_in), jnp.uint32, sharding=one_chip),
     )
